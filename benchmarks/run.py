@@ -65,6 +65,12 @@ Each prints `name,metric,value` CSV rows and writes
 experiments/bench_results.csv; the consensus benches additionally dump
 experiments/BENCH_consensus.json (the machine-readable perf trajectory
 tracked across PRs).
+
+consensus_backends and overlapped_consensus run each configuration in a
+child process.  A device belongs to one process, so those benches run
+first, before this process touches a JAX backend, and a child that fails
+makes the whole run exit nonzero.  The shard_map children need four
+devices: four chips, or four CPU devices on a host without an accelerator.
 """
 import argparse
 import os
@@ -74,9 +80,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.launch.compile_cache import enable_compile_cache
+
 RESULTS = []
 OUT = os.path.join(os.path.dirname(__file__), "..", "experiments")
 SMOKE = False     # set by --smoke: tiny sizes, seconds per bench
+FAILED = []       # "bench/config" of every child process that failed
 
 
 def S(full, smoke):
@@ -87,6 +96,32 @@ def S(full, smoke):
 def record(name, metric, value):
     RESULTS.append((name, metric, value))
     print(f"{name},{metric},{value}")
+
+
+def run_child(bench, tag, child, args):
+    """Run one configuration's child program; return its ``BENCH_JSON``
+    payload, or record an ``_error`` row, mark the run failed and return
+    None.  The result line is found by its sentinel prefix, never as "the
+    last stdout line": jax and engine logging can trail it."""
+    import json
+    import subprocess
+    import sys
+
+    src = os.path.abspath(os.path.join(os.path.dirname(__file__), "..",
+                                       "src"))
+    r = subprocess.run([sys.executable, "-c", child, *map(str, args)],
+                       capture_output=True, text=True, timeout=900,
+                       env={**os.environ, "PYTHONPATH": src})
+    sentinel = "BENCH_JSON "
+    line = next((ln for ln in reversed(r.stdout.splitlines())
+                 if ln.startswith(sentinel)), None)
+    if r.returncode == 0 and line is not None:
+        return json.loads(line[len(sentinel):])
+    err = (r.stderr.strip().splitlines()[-1][:120]
+           if r.stderr.strip() else "no BENCH_JSON line")
+    record(bench, f"{tag}_error", err.replace(",", ";"))
+    FAILED.append(f"{bench}/{tag}")
+    return None
 
 
 def bench_fig3_consensus():
@@ -400,10 +435,6 @@ def bench_consensus_backends():
     subprocess so ru_maxrss is a clean per-path peak; the parent checks the
     paths agree on the final parameters (allclose) and records peak-RSS and
     epoch throughput per backend."""
-    import json
-    import subprocess
-    import sys
-
     child = r'''
 import os, sys, json, time, resource
 backend = sys.argv[1]
@@ -488,31 +519,15 @@ if backend.startswith("shard_map_wire"):
 # stdout from jax/engine logging can never masquerade as the datapoint
 print("BENCH_JSON " + json.dumps(out))
 '''
-    src = os.path.abspath(os.path.join(os.path.dirname(__file__), "..",
-                                       "src"))
     results = {}
     epochs, d = S(5, 3), S(1_500_000, 100_000)
-    sentinel = "BENCH_JSON "
     for backend in ("gossip", "gossip_blocked", "shard_map",
                     "shard_map_wire", "shard_map_wire_int4"):
-        r = subprocess.run([sys.executable, "-c", child, backend,
-                            str(epochs), str(d)],
-                           capture_output=True, text=True, timeout=900,
-                           env={**os.environ, "PYTHONPATH": src})
-        # parse by sentinel prefix, never "the last stdout line": engine /
-        # jax logging can trail the datapoint, and a dead subprocess then
-        # records an error row instead of crashing the whole bench (the
-        # JSON writer merges key-level, so the other backends' fresh
-        # numbers still land and the dead one keeps its last datapoint)
-        line = next((ln for ln in reversed(r.stdout.splitlines())
-                     if ln.startswith(sentinel)), None)
-        if r.returncode != 0 or line is None:
-            err = (r.stderr.strip().splitlines()[-1][:120]
-                   if r.stderr.strip() else "no BENCH_JSON line")
-            record("consensus_backends", f"{backend}_error",
-                   err.replace(",", ";"))
+        out = run_child("consensus_backends", backend, child,
+                        (backend, epochs, d))
+        if out is None:
             continue
-        results[backend] = json.loads(line[len(sentinel):])
+        results[backend] = out
         record("consensus_backends", f"{backend}_peak_rss_mb",
                round(results[backend]["peak_rss_mb"], 1))
         record("consensus_backends", f"{backend}_epochs_per_s",
@@ -559,10 +574,6 @@ def bench_overlapped_consensus():
     the barrier, and the `staleness0_bitwise` boolean — a sha256 over the
     final server parameters proving the K=8 / staleness=0 megastep is
     BITWISE the barrier engine (the degeneration oracle, CI-gated)."""
-    import json
-    import subprocess
-    import sys
-
     child = r'''
 import os, sys, json, time, hashlib, resource
 import jax, jax.numpy as jnp, numpy as np
@@ -617,27 +628,16 @@ out = {
 # stdout from jax/engine logging can never masquerade as the datapoint
 print("BENCH_JSON " + json.dumps(out))
 '''
-    src = os.path.abspath(os.path.join(os.path.dirname(__file__), "..",
-                                       "src"))
     epochs, d = S(256, 32), S(10_000, 4_000)
     configs = (("barrier", 1, 0), ("superepoch8", 8, 0),
                ("superepoch8_stale1", 8, 1))
-    sentinel = "BENCH_JSON "
     results = {}
     for tag, k, s in configs:
-        r = subprocess.run([sys.executable, "-c", child, str(k), str(s),
-                            str(epochs), str(d)],
-                           capture_output=True, text=True, timeout=900,
-                           env={**os.environ, "PYTHONPATH": src})
-        line = next((ln for ln in reversed(r.stdout.splitlines())
-                     if ln.startswith(sentinel)), None)
-        if r.returncode != 0 or line is None:
-            err = (r.stderr.strip().splitlines()[-1][:120]
-                   if r.stderr.strip() else "no BENCH_JSON line")
-            record("overlapped_consensus", f"{tag}_error",
-                   err.replace(",", ";"))
+        out = run_child("overlapped_consensus", tag, child,
+                        (k, s, epochs, d))
+        if out is None:
             continue
-        results[tag] = json.loads(line[len(sentinel):])
+        results[tag] = out
         record("overlapped_consensus", f"{tag}_epochs_per_s",
                round(results[tag]["epochs_per_s"], 3))
         record("overlapped_consensus", f"{tag}_peak_rss_mb",
@@ -920,6 +920,7 @@ BENCHES = {
     "kernel_micro": bench_kernel_micro,
     "lm_epoch_throughput": bench_lm_epoch_throughput,
 }
+CHILD_BENCHES = ("consensus_backends", "overlapped_consensus")
 
 
 def main() -> None:
@@ -934,6 +935,7 @@ def main() -> None:
                          "meaningful")
     args = ap.parse_args()
     SMOKE = args.smoke
+    enable_compile_cache()
     if args.only:
         names = [n.strip() for n in args.only.split(",") if n.strip()]
         unknown = [n for n in names if n not in BENCHES]
@@ -942,6 +944,9 @@ def main() -> None:
                              f"{list(BENCHES)}")
     else:
         names = list(BENCHES)
+    # the benches with child processes first: a device belongs to one
+    # process, and this one holds it from its first JAX computation on
+    names.sort(key=lambda n: n not in CHILD_BENCHES)
     print("name,metric,value")
     for name in names:
         BENCHES[name]()
@@ -965,6 +970,8 @@ def main() -> None:
         for row in RESULTS:
             f.write(",".join(str(r) for r in row) + "\n")
     write_bench_consensus_json()
+    if FAILED:
+        raise SystemExit(f"benchmark child process(es) failed: {FAILED}")
 
 
 def write_bench_consensus_json() -> None:

@@ -98,22 +98,6 @@ from repro.comm import compressors as _compressors
 from repro.comm import error_feedback as _ef
 from repro.core.topology import lambda_2 as tp_lambda_2
 
-try:                                   # jax >= 0.6: public jax.shard_map
-    _shard_map = jax.shard_map
-    _CHECK_KW = "check_vma"
-except AttributeError:                 # jax 0.4.x: experimental location
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _CHECK_KW = "check_rep"
-
-
-def shard_map_compat(f, mesh, in_specs, out_specs, check=None):
-    """jax.shard_map across the 0.4.x -> 0.6 API move (the keyword for
-    replication checking was renamed check_rep -> check_vma)."""
-    kw = {} if check is None else {_CHECK_KW: check}
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **kw)
-
-
 def _mix_leaf(a: jax.Array, leaf: jax.Array) -> jax.Array:
     """new[i] = sum_j a[i, j] * leaf[j, ...] over the leading server axis.
 
@@ -1167,8 +1151,9 @@ def make_gossip_shard_map(mesh, t_server: int, leaf_specs: Any, *,
 
     out_specs = ((leaf_specs, leaf_specs)
                  if codec is not None and with_shipped else leaf_specs)
-    sm = shard_map_compat(body, mesh, (P(None, None), P(None), leaf_specs),
-                          out_specs, check=False)
+    sm = jax.shard_map(body, mesh=mesh,
+                       in_specs=(P(None, None), P(None), leaf_specs),
+                       out_specs=out_specs, check_vma=False)
     if codec is None:
         return lambda a, tree: sm(a, jnp.zeros((2,), jnp.uint32), tree)
 
@@ -1326,8 +1311,8 @@ def make_ring_gossip(mesh: jax.sharding.Mesh, axis_name: str, t_server: int,
             kd = jax.random.key_data(key)
         else:
             kd = jnp.zeros((2,), jnp.uint32)
-        return shard_map_compat(body, mesh, (P(None), specs),
-                                specs)(kd, tree)
+        return jax.shard_map(body, mesh=mesh, in_specs=(P(None), specs),
+                             out_specs=specs)(kd, tree)
 
     return run
 

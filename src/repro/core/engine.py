@@ -82,6 +82,14 @@ class DynamicFederationEngine:
     # (overlap.build_dfl_superepoch_step) and reads K epochs of metrics
     # back in one transfer.  1 = the per-epoch barrier loop (unchanged).
     superepoch: int = 1
+    # ``(state shardings, batch sharding)``: a NamedSharding pytree for the
+    # DFLState and one NamedSharding for every batch leaf, placing both on
+    # a ('server',) mesh, one server per device
+    # (``launch.sharding.fl_state_specs`` / ``fl_batch_spec``).  The jitted
+    # steps take and return the state so placed, so each device trains its
+    # own server's clients.  None leaves placement to the inputs (one
+    # device).  Set it with ``dataclasses.replace`` once the state exists.
+    shardings: Optional[Tuple[Any, Any]] = None
 
     def __post_init__(self):
         if self.obs is None:
@@ -106,13 +114,15 @@ class DynamicFederationEngine:
         # candidate (at least one honest server)
         if self.cfg.byzantine is not None:
             self.cfg.byzantine.validate(self.topo.num_servers)
-        if (self.faults.events and self.cfg.consensus_backend is not None
-                and getattr(self.cfg.consensus_backend, "mesh_bound", False)):
+        if self.faults.events and (
+                self.shardings is not None
+                or getattr(self.cfg.consensus_backend, "mesh_bound", False)):
             raise ValueError(
-                "a mesh-bound consensus backend (shard_map) cannot survive "
-                "fault surgery: the server axis is a physical mesh axis and "
-                "cannot change size with M — use consensus_mode="
-                "'gossip_blocked' for fault scenarios")
+                "a mesh-bound consensus backend (shard_map) or a server "
+                "placement cannot survive fault surgery: the server axis is "
+                "a physical mesh axis and cannot change size with M — use "
+                "consensus_mode='gossip_blocked' on one device for fault "
+                "scenarios")
         # original server ids still alive, in row order of the state arrays
         self.alive: List[int] = list(range(self.topo.num_servers))
         self._initial_m: int = self.topo.num_servers
@@ -216,9 +226,57 @@ class DynamicFederationEngine:
             # donate the carried state: without this every dynamic epoch
             # holds TWO full copies of client params + optimizer state (the
             # static trainer path has always donated — train.py)
-            self._steps[m] = jax.jit(dfl.build_dfl_epoch_step(
-                cfg, self.loss_fn, self.optimizer), donate_argnums=(0,))
+            self._steps[m] = self._jit(dfl.build_dfl_epoch_step(
+                cfg, self.loss_fn, self.optimizer), 0)
         return self._steps[m]
+
+    def _batch_sharding(self, k: int) -> Any:
+        """``self.shardings``' batch placement under ``k`` stacked epoch
+        axes (0: one epoch's batch, 1: a superepoch block's)."""
+        s = self.shardings[1]
+        return jax.sharding.NamedSharding(
+            s.mesh, jax.sharding.PartitionSpec(*(None,) * k, *s.spec))
+
+    def _place(self, batches: Any, k: int) -> Any:
+        """Put the batch where the placed step takes it.  An explicit put:
+        jit would move an uncommitted batch itself, but under a second
+        cache entry that ``compile_counts`` would read as a retrace."""
+        if self.shardings is None:
+            return batches
+        return jax.device_put(batches, self._batch_sharding(k))
+
+    def _to_step(self, state: dfl.DFLState) -> dfl.DFLState:
+        """The state as the jitted step takes it.  Placed, the PRNG key
+        crosses the jit boundary as raw key data, put at exactly the
+        state's sharding for it.  A typed key on a multi-device sharding
+        gives jit's dispatch cache a second entry at the third call, and so
+        does raw data whose spec merely equals the step's (``P(None)``
+        against ``P()``): still one trace and one compile, but
+        ``compile_counts`` would read a retrace."""
+        if self.shardings is None:
+            return state
+        return state._replace(rng=jax.device_put(
+            jax.random.key_data(state.rng), self.shardings[0].rng))
+
+    def _from_step(self, state: dfl.DFLState) -> dfl.DFLState:
+        return state if self.shardings is None else _typed_key(state)
+
+    def _jit(self, step: Callable, k: int) -> Callable:
+        """jit an epoch step (``k=0``) or a superepoch megastep (``k=1``:
+        its batch stacks an epoch axis), donating the carried state.
+        Placed, the state goes in and out and the batch goes in on the
+        server mesh, and the key as ``_to_step`` gives it; the schedule
+        operands and the metrics are left to the compiler."""
+        if self.shardings is None:
+            return jax.jit(step, donate_argnums=(0,))
+        state_sh = self.shardings[0]
+
+        def placed(state, *rest):
+            out = step(_typed_key(state), *rest)
+            return (_key_data(out[0]), *out[1:])
+        return jax.jit(placed, donate_argnums=(0,),
+                       in_shardings=(state_sh, self._batch_sharding(k), None),
+                       out_shardings=(state_sh,) + (None,) * (1 + k))
 
     def _super_step(self, k: int) -> Callable:
         """The jitted fused K-epoch megastep for the current federation
@@ -228,10 +286,9 @@ class DynamicFederationEngine:
         key = (m, k)
         if key not in self._super_steps:
             cfg = dataclasses.replace(self.cfg, topology=self.topo)
-            self._super_steps[key] = jax.jit(
+            self._super_steps[key] = self._jit(
                 overlap.build_dfl_superepoch_step(
-                    cfg, self.loss_fn, self.optimizer, k),
-                donate_argnums=(0,))
+                    cfg, self.loss_fn, self.optimizer, k), 1)
         return self._super_steps[key]
 
     def compile_counts(self) -> Dict[int, int]:
@@ -455,7 +512,7 @@ class DynamicFederationEngine:
             mask_np = self.participation.mask(epoch, m, n)
             a_np = self.topology_schedule.mixing(self.topo, epoch)
             sigma_prod = self._tracker.update(a_np, self.topo.t_server)
-            batches = batch_fn(epoch, tuple(self.alive))
+            batches = self._place(batch_fn(epoch, tuple(self.alive)), 0)
             lam2 = (jnp.float32(tp.lambda_2(a_np)) if self._needs_spectral
                     else None)
             byz_np = None
@@ -484,7 +541,8 @@ class DynamicFederationEngine:
             # untraced path dispatches exactly as before
             programs_before = int(step._cache_size()) if tracer else 0
             t0 = tracer.now() if tracer else 0
-            state, metrics = step(state, batches, sched)
+            state, metrics = step(self._to_step(state), batches, sched)
+            state = self._from_step(state)
             if tracer is not None:
                 jax.block_until_ready(state)
                 self._trace_step(epoch_span, epoch, m, m_known,
@@ -614,7 +672,8 @@ class DynamicFederationEngine:
                 jnp.asarray(sb.mask), jnp.asarray(sb.mixing),
                 None if sb.lam2 is None else jnp.asarray(sb.lam2),
                 None if sb.byz is None else jnp.asarray(sb.byz))
-            batches = jax.tree.map(lambda *xs: jnp.stack(xs), *batch_list)
+            batches = self._place(
+                jax.tree.map(lambda *xs: jnp.stack(xs), *batch_list), 1)
             wire = None
             if self._bytes is not None:
                 row_bytes, elems = self._wire_row_bytes(state)
@@ -625,7 +684,9 @@ class DynamicFederationEngine:
             step = self._super_step(k)
             programs_before = int(step._cache_size()) if tracer else 0
             t0 = tracer.now() if tracer else 0
-            state, metrics, psw = step(state, batches, sched)
+            state, metrics, psw = step(self._to_step(state), batches,
+                                       sched)
+            state = self._from_step(state)
             if tracer is not None:
                 jax.block_until_ready(state)
                 self._trace_superepoch(
@@ -692,6 +753,14 @@ class DynamicFederationEngine:
                 for key, v in rec.items():
                     history.setdefault(key, []).append(v)
         return state, history
+
+
+def _key_data(state: dfl.DFLState) -> dfl.DFLState:
+    return state._replace(rng=jax.random.key_data(state.rng))
+
+
+def _typed_key(state: dfl.DFLState) -> dfl.DFLState:
+    return state._replace(rng=jax.random.wrap_key_data(state.rng))
 
 
 def make_engine(topology: FLTopology, loss_fn: dfl.LossFn,

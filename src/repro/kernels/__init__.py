@@ -2,8 +2,10 @@
 
 Each kernel ships three layers: ``<name>.py`` (pl.pallas_call + BlockSpec),
 ``ops.py`` (jitted layout-adapting wrapper the models call), ``ref.py``
-(pure-jnp oracle the sweep tests compare against).  On this CPU container
-all kernels run with ``interpret=True``; on TPU the Mosaic path compiles.
+(pure-jnp oracle the sweep tests compare against).  Every kernel entry
+point takes ``interpret`` with no default, so each call states its mode:
+``ops.py`` passes ``interpret=True`` only when the backend is not a TPU,
+and the CPU tests pass it explicitly.
 """
 from repro.kernels import ops, ref
 from repro.kernels.ops import (consensus_mix_pytree, flash_attention,

@@ -69,7 +69,7 @@ def _mix_kernel(a_ref, w_ref, o_ref, *, total_d: int, block_d: int):
 
 
 def consensus_mix_2d(a_eff: jax.Array, w: jax.Array, *, block_d: int = 2048,
-                     interpret: bool = True) -> jax.Array:
+                     interpret: bool) -> jax.Array:
     """w: (M, D); a_eff: (M, M).  Returns A_eff @ w, one HBM pass."""
     m, d = w.shape
     block_d = min(block_d, d)
@@ -124,7 +124,7 @@ def _quant_mix_kernel(a_ref, w_ref, u_ref, o_ref, *, block_d: int,
 def quantized_consensus_mix_2d(a_eff: jax.Array, w: jax.Array,
                                dither: jax.Array, *, bits: int = 8,
                                chunk: int = 256, block_d: int = 2048,
-                               interpret: bool = True) -> jax.Array:
+                               interpret: bool) -> jax.Array:
     """Fused quantize -> mix -> dequantize:  A_eff @ D(C(w))  in one pass.
 
     ``w``: (M, D) flattened server models; ``a_eff``: the (collapsed)
@@ -220,7 +220,7 @@ def quantized_gossip_round_2d(a: jax.Array, codes: jax.Array,
                               scales: jax.Array, ref: jax.Array,
                               dither: jax.Array, *, bits: int = 8,
                               chunk: int = 256, block_d: int = 2048,
-                              interpret: bool = True):
+                              interpret: bool):
     """Fused gather-dequant-mix-requant: one delta-coded ``wire="physical"``
     gossip round after the all-gather, in one HBM pass — the single-chip
     half of ``core.consensus.make_gossip_shard_map``'s codec mode.
@@ -334,7 +334,7 @@ def _wire_encode_kernel(w_ref, r_ref, u_ref, oq_ref, os_ref, *,
 def quantized_gossip_encode_2d(w: jax.Array, ref: jax.Array,
                                dither: jax.Array, *, bits: int = 8,
                                chunk: int = 256, block_d: int = 2048,
-                               interpret: bool = True):
+                               interpret: bool):
     """Fused innovation + encode: ``C(w - ref; dither)`` in one HBM pass —
     the SEND side of the physical wire, what every server computes
     immediately before the round's collective (round 0, ``ref = 0``,
@@ -441,7 +441,7 @@ def bucketed_gossip_round_2d(a: jax.Array, codes: jax.Array,
                              scales: jax.Array, ref: jax.Array,
                              acc: jax.Array, dither: jax.Array, *,
                              bits: int = 8, chunk: int = 256,
-                             block_d: int = 2048, interpret: bool = True):
+                             block_d: int = 2048, interpret: bool):
     """Fused encode→gather→dequant→accumulate→mix→requant, bucketed: one
     round of ``core.consensus.gossip_scan_wire_bucketed``'s band-carried
     recursion after the all-gather, in one HBM pass.
@@ -579,7 +579,7 @@ def bucketed_gossip_round_pipelined_2d(a: jax.Array, codes: jax.Array,
                                        ref: jax.Array, acc: jax.Array,
                                        dither: jax.Array, *, bits: int = 8,
                                        chunk: int = 256, block_d: int = 2048,
-                                       interpret: bool = True):
+                                       interpret: bool):
     """Fused SOFTWARE-PIPELINED bucketed round: one round of
     ``core.consensus.gossip_scan_wire_bucketed``'s bounded-staleness
     recursion (``staleness >= 1``) in one HBM pass.

@@ -120,11 +120,11 @@ def flash_attention_bhsd(q: jax.Array, k: jax.Array, v: jax.Array, *,
                          softcap: Optional[float] = None,
                          scale: Optional[float] = None,
                          block_q: int = 128, block_k: int = 128,
-                         interpret: bool = True) -> jax.Array:
+                         interpret: bool) -> jax.Array:
     """Layout (b, h, s, hd) / (b, kvh, s, hd).  Returns (b, h, sq, hd).
 
-    ``interpret=True`` runs the kernel body in Python on CPU (this container);
-    on TPU pass ``interpret=False``.
+    ``interpret`` has no default: ``True`` runs the kernel body in Python
+    on the CPU, ``False`` compiles it with Mosaic for the TPU.
     """
     b, h, sq, hd = q.shape
     kvh, sk = k.shape[1], k.shape[2]

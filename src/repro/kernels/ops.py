@@ -2,8 +2,8 @@
 
 These adapt the model-zoo layouts to the kernel-native layouts, pad where a
 block constraint requires it, and pick interpret mode automatically:
-``interpret=True`` whenever the backend has no TPU (this container), the
-real Mosaic path on TPU.  Models call these via ``ApplyOptions(attn_impl=
+``interpret=True`` whenever the backend is not a TPU, the real Mosaic
+path on TPU.  Models call these via ``ApplyOptions(attn_impl=
 "pallas")``; the default model path stays the jnp reference so CPU dry-runs
 lower without Pallas in the HLO.
 """

@@ -26,7 +26,7 @@ def _rmsnorm_kernel(x_ref, s_ref, o_ref, *, eps: float, d: int):
 
 
 def rmsnorm_2d(x: jax.Array, scale: jax.Array, *, eps: float = 1e-6,
-               block_rows: int = 256, interpret: bool = True) -> jax.Array:
+               block_rows: int = 256, interpret: bool) -> jax.Array:
     """x: (rows, d) — callers flatten leading axes; scale: (d,)."""
     rows, d = x.shape
     block_rows = min(block_rows, rows)

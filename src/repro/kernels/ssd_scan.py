@@ -91,7 +91,7 @@ def _ssd_kernel(x_ref, b_ref, c_ref, dt_ref, a_ref, y_ref, state_out_ref,
 
 def ssd_scan_bhs(xs: jax.Array, bs: jax.Array, cs: jax.Array, dt: jax.Array,
                  a_coef: jax.Array, *, chunk: int = 128,
-                 interpret: bool = True) -> Tuple[jax.Array, jax.Array]:
+                 interpret: bool) -> Tuple[jax.Array, jax.Array]:
     """Layout (b, nh, s, hd) for x, (b, nh, s, ds) for B/C (already head-
     broadcast), (b, nh, s, 1) f32 for dt, (nh,) for a_coef.
 

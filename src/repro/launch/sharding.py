@@ -112,6 +112,9 @@ def _tree_specs(tree: Any, lead: Tuple[Optional[str], ...],
     shape = dict(zip(mesh.axis_names, mesh.devices.shape))
     tp = shape.get(tp_axis, 1) if tp_axis else 1
     fs = shape.get(fsdp_axis, 1) if fsdp_axis else 1
+    # a lead axis the mesh lacks (e.g. 'client' on a ('server',) mesh,
+    # one server per chip) replicates that dim
+    lead = tuple(ax if ax in shape else None for ax in lead)
 
     def leaf_spec(path, leaf):
         if not hasattr(leaf, "ndim") or leaf.ndim == 0:
@@ -148,14 +151,17 @@ def serve_param_specs(params: Any, mesh: Mesh, *,
 
 def fl_batch_spec(mesh: Mesh, batch_div_replica: bool,
                   batch_over_model: bool = False) -> P:
-    """Per-epoch batch leaves (T_C, M, N, b, ...)."""
+    """Per-epoch batch leaves (T_C, M, N, b, ...); axes the mesh lacks are
+    left out (on a ('server',) mesh: ``P(None, 'server', None, None)``)."""
+    names = set(mesh.axis_names)
     axes = []
-    if batch_div_replica:
+    if batch_div_replica and "replica" in names:
         axes.append("replica")
-    if batch_over_model:
+    if batch_over_model and "model" in names:
         axes.append("model")
     b_axis = tuple(axes) if axes else None
-    return P(None, "server", "client", b_axis)
+    lead = [ax if ax in names else None for ax in ("server", "client")]
+    return P(None, *lead, b_axis)
 
 
 def fl_state_specs(state: Any, mesh: Mesh, *,

@@ -1,8 +1,14 @@
-"""DFL trainer driver (runnable at CPU scale; the full-size path is the
-same code lowered by dryrun.py onto the production mesh).
+"""DFL trainer driver: the smoke preset runs on the CPU, ``--full`` trains
+the published widths on a TPU (``chip_smoke.py`` runs smollm-360m through
+``train_dynamic`` on one v5e chip, at a federation shape that fits it).
 
     PYTHONPATH=src python -m repro.launch.train --arch smollm-360m --smoke \
         --servers 2 --clients 2 --t-client 4 --t-server 5 --epochs 3
+
+With at least M devices (a four-chip host at M=4) and no fault schedule the
+dynamic driver places one server per device: the DFL state and the batches
+shard over a ('server',) mesh, so each device trains its own server's
+clients.
 
 Runs the paper's Algorithm 1 end to end: T_C local SGD steps per client on
 per-client synthetic LM shards, per-server aggregation, T_S gossip rounds,
@@ -12,7 +18,8 @@ and Lemma 3 quantities) every epoch, with checkpointing.
 from __future__ import annotations
 
 import argparse
-from typing import Callable, Optional, Tuple
+import dataclasses
+from typing import Callable, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -26,12 +33,19 @@ from repro.core import (DFLConfig, FLTopology, build_dfl_epoch_step,
                         TopologySchedule, load_participation_trace)
 from repro.data import DataConfig, FLDataPipeline
 from repro.launch import sharding as shd
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import transformer as tf
 from repro.obs import (ConsoleSink, JSONLSink, MetricsHub, Observability,
-                       Tracer)
+                       Sink, Tracer)
 from repro.optim import sgd
 
 CONSENSUS_BACKENDS = ("auto", "einsum", "blocked", "shard_map")
+
+
+def server_mesh(m: int) -> jax.sharding.Mesh:
+    """A ('server',) mesh over the first ``m`` devices: one server each."""
+    return jax.sharding.Mesh(np.array(jax.devices()[:m]).reshape(m),
+                             ("server",))
 
 
 def resolve_consensus_backend(backend: str, consensus_mode: str,
@@ -77,8 +91,7 @@ def resolve_consensus_backend(backend: str, consensus_mode: str,
             f"axis of size M={m} but only {ndev} device(s) are visible; "
             f"set XLA_FLAGS=--xla_force_host_platform_device_count={m} "
             f"on CPU")
-    mesh = jax.sharding.Mesh(np.array(jax.devices()[:m]).reshape(m),
-                             ("server",))
+    mesh = server_mesh(m)
     server_abs = jax.eval_shape(
         lambda p: jax.tree.map(
             lambda x: jnp.zeros((m,) + x.shape, x.dtype), p), params)
@@ -92,14 +105,17 @@ def resolve_consensus_backend(backend: str, consensus_mode: str,
 
 def _setup_lm(arch_id, smoke, servers, clients, t_client, t_server, graph,
               gamma, seq_len, per_client_batch, seed, attn_impl,
-              mixing="symmetric"):
+              mixing="symmetric", num_layers=None):
     """Shared trainer scaffolding: arch config, topology, loss, optimizer,
     data pipeline (used by both the static and the dynamic driver).
 
     ``mixing`` is the DFLConfig interpretation (symmetric | row_stochastic |
     push_sum); the directed paths need row-stochastic out-degree weights on
-    the topology, symmetric gossip needs Metropolis weights."""
+    the topology, symmetric gossip needs Metropolis weights.
+    ``num_layers`` cuts the depth and keeps every width."""
     cfg = get_smoke(arch_id) if smoke else get_arch(arch_id)
+    if num_layers is not None:
+        cfg = dataclasses.replace(cfg, num_layers=num_layers)
     topo_mixing = "out_degree" if mixing != "symmetric" else "metropolis"
     topo = FLTopology(num_servers=servers, clients_per_server=clients,
                       t_client=t_client, t_server=t_server, graph_kind=graph,
@@ -117,14 +133,18 @@ def _setup_lm(arch_id, smoke, servers, clients, t_client, t_server, graph,
 def _make_observability(*, log_every: int = 1,
                         telemetry_jsonl: Optional[str] = None,
                         chrome_trace: Optional[str] = None,
-                        run_info: Optional[dict] = None) -> Observability:
+                        run_info: Optional[dict] = None,
+                        sinks: Sequence[Sink] = ()) -> Observability:
     """The trainers' standard obs bundle: a ConsoleSink (the one place the
     old ``epoch ... loss=...`` prints now live), an optional JSONL
-    telemetry stream, an optional span tracer for a Chrome trace export,
-    and the convergence watchdogs — see docs/observability.md."""
+    telemetry stream, the caller's own ``sinks``, an optional span tracer
+    for a Chrome trace export, and the convergence watchdogs — see
+    docs/observability.md."""
     hub = MetricsHub([ConsoleSink(log_every=log_every)])
     if telemetry_jsonl:
         hub.add_sink(JSONLSink(telemetry_jsonl, run_info=run_info))
+    for sink in sinks:
+        hub.add_sink(sink)
     return Observability(hub=hub,
                          tracer=Tracer() if chrome_trace else None,
                          monitor=True)
@@ -295,7 +315,9 @@ def train_dynamic(arch_id: str, *, smoke: bool = True, servers: int = 2,
                   seed: int = 0, log_every: int = 1,
                   attn_impl: str = "reference",
                   telemetry_jsonl: Optional[str] = None,
-                  chrome_trace: Optional[str] = None) -> dict:
+                  chrome_trace: Optional[str] = None,
+                  sinks: Sequence[Sink] = (),
+                  num_layers: Optional[int] = None) -> dict:
     """Dynamic-federation LM training: the same Algorithm-1 cycle driven by
     the scenario engine — partial client participation, per-epoch degraded
     server graphs, scheduled server failure/rejoin (``faults`` is the
@@ -314,10 +336,19 @@ def train_dynamic(arch_id: str, *, smoke: bool = True, servers: int = 2,
     element-identical at any K; checkpoint cadence coarsens to block
     boundaries); ``staleness=s > 0`` lets gossip round t mix codes from
     round t-s, overlapping each round's collective with its compute
-    (changes the consensus operator — see docs/dynamic_federation.md)."""
+    (changes the consensus operator — see docs/dynamic_federation.md).
+
+    With at least ``servers`` devices and no ``faults`` each server gets its
+    own device (``server_mesh``); otherwise the servers share device 0.
+    ``sinks`` are extra metric sinks (``repro.obs.Sink``) that see every
+    epoch event.
+    ``num_layers`` cuts the model's depth at its published widths: a
+    four-chip run of the physical wire fits smollm-360m's 32 layers in no
+    chip's memory (see ``chip_smoke.py``)."""
     cfg, topo, loss_fn, optimizer, pipe = _setup_lm(
         arch_id, smoke, servers, clients, t_client, t_server, graph, gamma,
-        seq_len, per_client_batch, seed, attn_impl, mixing=mixing)
+        seq_len, per_client_batch, seed, attn_impl, mixing=mixing,
+        num_layers=num_layers)
     params = tf.init_params(jax.random.key(seed), cfg)
     consensus_mode, backend = resolve_consensus_backend(
         consensus_backend, consensus_mode, topo, params,
@@ -355,18 +386,20 @@ def train_dynamic(arch_id: str, *, smoke: bool = True, servers: int = 2,
                                   seed=seed + 1)
     else:
         tsched = TopologySchedule()                        # static
+    fault_schedule = FaultSchedule.parse(faults)
     obs = _make_observability(
         log_every=log_every, telemetry_jsonl=telemetry_jsonl,
         chrome_trace=chrome_trace,
         run_info={"arch": cfg.name, "driver": "train_dynamic",
-                  "servers": servers})
+                  "servers": servers},
+        sinks=sinks)
     engine = make_engine(topo, loss_fn, optimizer,
                          consensus_mode=consensus_mode, mixing=mixing,
                          consensus_backend=backend,
                          compression=compression,
                          error_feedback=error_feedback, wire=wire,
                          participation=part, topology_schedule=tsched,
-                         faults=FaultSchedule.parse(faults),
+                         faults=fault_schedule,
                          byzantine=(ByzantineSchedule.parse(byzantine,
                                                             seed=seed)
                                     if byzantine else None),
@@ -375,6 +408,19 @@ def train_dynamic(arch_id: str, *, smoke: bool = True, servers: int = 2,
 
     state = init_dfl_state(engine.cfg, params, optimizer,
                            jax.random.key(seed + 1))
+    # the client copies are all the run needs: at published widths the
+    # initial tree would hold another full model of device memory
+    del params
+    # fault surgery changes M, which a server mesh cannot follow: a fault
+    # scenario runs unplaced
+    if not fault_schedule.events and 1 < servers <= jax.device_count():
+        mesh = server_mesh(servers)
+        state_sh = shd.named(shd.fl_state_specs(state, mesh, tp_axis=None),
+                             mesh)
+        batch_sh = jax.sharding.NamedSharding(
+            mesh, shd.fl_batch_spec(mesh, batch_div_replica=False))
+        state = jax.device_put(state, state_sh)
+        engine = dataclasses.replace(engine, shardings=(state_sh, batch_sh))
 
     def batch_fn(epoch, alive):
         return pipe.epoch_batches(epoch, server_ids=alive)
@@ -422,7 +468,8 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--arch", default="smollm-360m")
     p.add_argument("--smoke", action="store_true", default=True)
     p.add_argument("--full", dest="smoke", action="store_false",
-                   help="full-size config (only sensible on a real pod)")
+                   help="published widths (runs on one TPU v5e chip; "
+                        "see chip_smoke.py for a shape that fits)")
     p.add_argument("--servers", type=int, default=2)
     p.add_argument("--clients", type=int, default=2)
     p.add_argument("--t-client", type=int, default=4)
@@ -529,6 +576,7 @@ def build_parser() -> argparse.ArgumentParser:
 
 def main() -> None:
     args = build_parser().parse_args()
+    enable_compile_cache()
     kw = dict(smoke=args.smoke, servers=args.servers, clients=args.clients,
               t_client=args.t_client, t_server=args.t_server,
               epochs=args.epochs, seq_len=args.seq_len,

@@ -359,6 +359,20 @@ def test_trainer_runs_gossip_blocked_end_to_end():
     assert res["history"]["disagreement"][-1] < 1e-2
 
 
+def test_dynamic_trainer_depth_cut_and_extra_sinks():
+    """train_dynamic cuts the depth and keeps the widths (``num_layers``),
+    and hands every epoch event to the caller's ``sinks``."""
+    from repro.launch.train import train_dynamic
+    from repro.obs import MemorySink
+    sink = MemorySink()
+    res = train_dynamic("smollm-360m", servers=2, clients=1, t_client=1,
+                        t_server=1, epochs=2, seq_len=16, per_client_batch=1,
+                        num_layers=1, sinks=(sink,), log_every=100)
+    assert res["cfg"].num_layers == 1 and res["cfg"].d_model == 120
+    assert sink.history()["loss"] == res["history"]["loss"]
+    assert np.isfinite(res["history"]["loss"]).all()
+
+
 # ---------------------------------------------------------------------------
 # shard_map backend (multi-device): subprocess, slow tier
 # ---------------------------------------------------------------------------

@@ -22,7 +22,7 @@ def test_consensus_mix_2d(m, d, block):
     a = jnp.asarray(collapse_mixing(
         tp.metropolis_weights(tp.ring_graph(m)), 7), jnp.float32)
     w = jax.random.normal(KEY, (m, d))
-    out = consensus_mix_2d(a, w, block_d=block)
+    out = consensus_mix_2d(a, w, block_d=block, interpret=True)
     ref = consensus_mix_ref(a, w)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
@@ -43,7 +43,7 @@ def test_quantized_consensus_mix_matches_compressor_oracle(m, d, bits,
     w = jax.random.normal(jax.random.fold_in(KEY, d), (m, d)) * 3
     u = jax.random.uniform(jax.random.fold_in(KEY, d + 1), (m, d))
     out = quantized_consensus_mix_2d(a, w, u, bits=bits, chunk=chunk,
-                                     block_d=block)
+                                     block_d=block, interpret=True)
     q = StochasticQuantizer(bits=bits, chunk=chunk)
     ref = a @ q.decompress(q.compress(w, dither=u), d)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
@@ -54,9 +54,10 @@ def test_quantized_consensus_mix_validates():
     a = jnp.eye(2)
     w = jnp.ones((2, 8))
     with pytest.raises(ValueError, match="bits"):
-        quantized_consensus_mix_2d(a, w, w, bits=3)
+        quantized_consensus_mix_2d(a, w, w, bits=3, interpret=True)
     with pytest.raises(ValueError, match="divide"):
-        quantized_consensus_mix_2d(a, w, w, chunk=3, block_d=8)
+        quantized_consensus_mix_2d(a, w, w, chunk=3, block_d=8,
+                                   interpret=True)
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
@@ -65,7 +66,7 @@ def test_consensus_mix_dtypes(dtype):
     a = jnp.asarray(collapse_mixing(
         tp.metropolis_weights(tp.complete_graph(m)), 3), jnp.float32)
     w = jax.random.normal(KEY, (m, 512)).astype(dtype)
-    out = consensus_mix_2d(a, w, block_d=128)
+    out = consensus_mix_2d(a, w, block_d=128, interpret=True)
     assert out.dtype == dtype
     ref = consensus_mix_ref(a, w)
     tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5

@@ -106,3 +106,69 @@ def test_mesh_specs_validate():
     spec = FLMeshSpec(num_servers=4, clients_per_server=4, fsdp=1, tp=16)
     assert spec.total_devices() == 256
     assert spec.devices_per_client == 16
+
+
+# ---------------------------------------------------------------------------
+# one server per device, the compile cache, and the chip entry point
+# ---------------------------------------------------------------------------
+
+REPO = __import__("pathlib").Path(__file__).resolve().parents[1]
+
+
+def test_server_mesh_specs_replicate_absent_axes():
+    """On a ('server',) mesh (one server per chip) the client grid dim and
+    the batch's client dim replicate instead of naming a missing axis."""
+    from jax.sharding import PartitionSpec as P
+
+    from repro.launch import sharding as shd
+    from repro.launch.train import server_mesh
+    mesh = server_mesh(1)
+    state = {"w": jnp.zeros((1, 2, 3, 4)), "count": jnp.zeros(())}
+    specs = shd.fl_state_specs(state, mesh, tp_axis=None)
+    assert specs["w"] == P("server", None, None, None)
+    assert specs["count"] == P()
+    assert shd.fl_batch_spec(mesh, batch_div_replica=True,
+                             batch_over_model=True) == P(None, "server",
+                                                         None, None)
+    shd.named(specs, mesh)          # every named axis exists on the mesh
+
+
+def test_compile_cache_dir_honours_environment(monkeypatch, tmp_path):
+    from repro.launch.compile_cache import compile_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert compile_cache_dir() == str(tmp_path)
+
+
+def test_compile_cache_dir_defaults_to_the_ignored_checkout_dir(monkeypatch):
+    from repro.launch.compile_cache import compile_cache_dir
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    assert compile_cache_dir() == str(REPO / ".jax_cache")
+    assert ".jax_cache/" in (REPO / ".gitignore").read_text().split()
+
+
+def test_enable_compile_cache_points_jax_there(monkeypatch, tmp_path):
+    from repro.launch.compile_cache import enable_compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    try:
+        assert enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == str(tmp_path)
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+    assert jax.config.jax_compilation_cache_dir == before
+
+
+def test_chip_smoke_refuses_without_a_tpu(capsys, monkeypatch):
+    """Run as a script on a host whose JAX has no TPU, chip_smoke.py exits
+    nonzero before any work and prints no result line."""
+    import runpy
+    import sys
+    if jax.devices()[0].platform == "tpu":
+        pytest.skip("this host has a TPU: the smoke run would train")
+    monkeypatch.setattr(sys, "argv", ["chip_smoke.py"])
+    with pytest.raises(SystemExit) as exc:
+        runpy.run_path(str(REPO / "chip_smoke.py"), run_name="__main__")
+    assert exc.value.code not in (0, None)
+    out = capsys.readouterr()
+    assert '"ok"' not in out.out
+    assert "no TPU" in out.err

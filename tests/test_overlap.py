@@ -390,7 +390,7 @@ def test_pipelined_kernel_matches_stale_oracle(bits):
                                        acc, dither)
     ka, kr, kq, ks = jax.jit(
         lambda *xs: bucketed_gossip_round_pipelined_2d(
-            *xs, bits=bits, chunk=chunk, block_d=512))(
+            *xs, bits=bits, chunk=chunk, block_d=512, interpret=True))(
         a, old_c, old_s, w, ref, acc, dither)
     # the kernel ships UNPACKED codes; unpack the oracle's for bits=4
     oq_flat = np.asarray(codec.code_chunks(oq, d)).reshape(m, d)
@@ -406,9 +406,9 @@ def test_pipelined_kernel_validation():
     s = jnp.ones((2, 1), jnp.float32)
     with pytest.raises(ValueError, match="bits"):
         bucketed_gossip_round_pipelined_2d(jnp.eye(2), c, s, z, z, z, z,
-                                           bits=3, chunk=128)
+                                           bits=3, chunk=128, interpret=True)
     with pytest.raises(ValueError, match="divide D"):
         bucketed_gossip_round_pipelined_2d(jnp.eye(2), c[:, :100], s,
                                            z[:, :100], z[:, :100],
                                            z[:, :100], z[:, :100],
-                                           chunk=32)
+                                           chunk=32, interpret=True)

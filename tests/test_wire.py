@@ -533,7 +533,7 @@ def test_quantized_gossip_round_kernel_bitwise(bits, rng_key):
     def kernel(codes, scales, ref, u):
         return quantized_gossip_round_2d(a, codes, scales, ref, u,
                                          bits=bits, chunk=chunk,
-                                         block_d=256)
+                                         block_d=256, interpret=True)
 
     codes_r, scales_r = comp.data, comp.scale
     codes_k, scales_k = comp.data, comp.scale
@@ -556,10 +556,12 @@ def test_quantized_gossip_round_kernel_validation(rng_key):
     ref = jnp.zeros((M, 100), jnp.float32)
     with pytest.raises(ValueError, match="divide D"):
         quantized_gossip_round_2d(_ring(), codes, jnp.ones((M, 4)), ref,
-                                  jnp.zeros((M, 100)), chunk=32)
+                                  jnp.zeros((M, 100)), chunk=32,
+                                  interpret=True)
     with pytest.raises(ValueError, match="bits"):
         quantized_gossip_round_2d(_ring(), codes, jnp.ones((M, 4)), ref,
-                                  jnp.zeros((M, 100)), bits=3, chunk=25)
+                                  jnp.zeros((M, 100)), bits=3, chunk=25,
+                                  interpret=True)
 
 
 @pytest.mark.parametrize("bits", [8, 4])
@@ -604,12 +606,12 @@ def test_bucketed_round_kernels_bitwise(bits, rng_key):
     def kernels(w0):
         codes, scales = quantized_gossip_encode_2d(
             w0, jnp.zeros((m, d)), u[0], bits=bits, chunk=chunk,
-            block_d=block_d)
+            block_d=block_d, interpret=True)
         ref, accum, outs = jnp.zeros((m, d)), jnp.zeros((m, d)), []
         for t in range(3):
             accum, ref, nxt_c, nxt_s = bucketed_gossip_round_2d(
                 a, codes, scales, ref, accum, u[t + 1], bits=bits,
-                chunk=chunk, block_d=block_d)
+                chunk=chunk, block_d=block_d, interpret=True)
             outs.append((codes, scales, ref, accum))
             codes, scales = nxt_c, nxt_s
         return outs
@@ -627,13 +629,13 @@ def test_bucketed_kernel_validation():
                                              quantized_gossip_encode_2d)
     w = jnp.zeros((M, 100))
     with pytest.raises(ValueError, match="bits"):
-        quantized_gossip_encode_2d(w, w, w, bits=3)
+        quantized_gossip_encode_2d(w, w, w, bits=3, interpret=True)
     with pytest.raises(ValueError, match="divide D"):
-        quantized_gossip_encode_2d(w, w, w, chunk=32)
+        quantized_gossip_encode_2d(w, w, w, chunk=32, interpret=True)
     codes = jnp.zeros((M, 100), jnp.int8)
     with pytest.raises(ValueError, match="divide D"):
         bucketed_gossip_round_2d(_ring(), codes, jnp.ones((M, 4)), w, w,
-                                 w, chunk=32)
+                                 w, chunk=32, interpret=True)
 
 
 # ---------------------------------------------------------------------------
