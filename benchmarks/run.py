@@ -818,8 +818,9 @@ def bench_byzantine_consensus():
 def bench_obs_phases():
     """The repro.obs stack on a full dynamic scenario (sampled
     participation + faulty links + drop/rejoin churn + physical int8+EF
-    wire): per-phase wall breakdown from the span tracer (local vs gossip
-    vs surgery vs host aggregation), obs-on vs obs-off overhead, the
+    wire): per-phase host wall time from the span tracer (the engine's
+    host phases; the device's phases are named scopes, seen only in a
+    jax.profiler trace), obs-on vs obs-off overhead, the
     bitwise-inertness cross-check, and validating JSONL + Chrome trace
     artifacts for CI to upload."""
     from repro.core import (FLTopology, FaultEvent, FaultSchedule,
@@ -887,8 +888,8 @@ def bench_obs_phases():
     phase_s = {}
     for sp in tracer.spans:
         phase_s[sp.name] = phase_s.get(sp.name, 0.0) + sp.duration_ns / 1e9
-    for name in ("local-period", "gossip-period", "fault-surgery",
-                 "host-aggregation"):
+    for name in ("fault-surgery", "schedule", "batch", "dispatch",
+                 "readback", "host-aggregation"):
         record("obs_phases", f"phase_{name.replace('-', '_')}_s",
                round(phase_s.get(name, 0.0), 4))
     compiles = [ev["args"]["cause"] for ev in tracer.instants

@@ -476,39 +476,43 @@ def gossip_scan_wire_bucketed(a: jax.Array, tree: Any, t_server: int,
     leaves, treedef = jax.tree.flatten(tree)
     m = leaves[0].shape[0]
     dtype = leaves[0].dtype
-    flat = _bucket_flat(leaves)
-    d_tot = flat.shape[1]
-    blk, nb = _compressors.bucket_block(d_tot, block, codec.chunk)
-    d_pad = nb * blk
-    if d_pad != d_tot:
-        flat = jnp.pad(flat, ((0, 0), (0, d_pad - d_tot)))
+    with jax.named_scope("wire_pack"):
+        flat = _bucket_flat(leaves)
+        d_tot = flat.shape[1]
+        blk, nb = _compressors.bucket_block(d_tot, block, codec.chunk)
+        d_pad = nb * blk
+        if d_pad != d_tot:
+            flat = jnp.pad(flat, ((0, 0), (0, d_pad - d_tot)))
     a32 = a.astype(jnp.float32)
     zeros = jnp.zeros((m, d_pad), jnp.float32)
 
     if staleness == 0:
         def one_round(t, carry):
             w, ref, acc = carry        # (m, d_pad): wire dtype, f32, f32
-            delta = w.astype(jnp.float32) - ref
-            dither = _bucket_dither_rows(codec, key, m, d_pad, rnd=t)
-            codes, scales = codec.encode_block(delta, dither)
+            with jax.named_scope("wire_encode"):
+                delta = w.astype(jnp.float32) - ref
+                dither = _bucket_dither_rows(codec, key, m, d_pad, rnd=t)
+                codes, scales = codec.encode_block(delta, dither)
             # fused dequantize-and-mix, folded exactly like the shard_map
             # round body: per-chunk scales (and the mixing weight) broadcast
             # onto raw f32 codes, one server term at a time — the same
             # scale-times-code and weight-times-scale products in the same
             # order, which is what keeps the simulation bit-identical to the
             # physical program
-            c3 = codec.code_chunks(codes, d_pad)       # (m, nc, chunk)
-            ref = ref + (c3 * scales[..., None]).reshape(m, d_pad)
-            ws = a32[:, :, None] * scales              # (m, m, nc): ws[i, j]
-            acc3 = acc.reshape(m, -1, codec.chunk)
-            for j in range(m):
-                acc3 = acc3 + ws[:, j, :, None] * c3[j]
-            acc = acc3.reshape(m, d_pad)
-            return acc.astype(dtype), ref, acc
+            with jax.named_scope("wire_decode_mix"):
+                c3 = codec.code_chunks(codes, d_pad)   # (m, nc, chunk)
+                ref = ref + (c3 * scales[..., None]).reshape(m, d_pad)
+                ws = a32[:, :, None] * scales          # (m, m, nc): ws[i, j]
+                acc3 = acc.reshape(m, -1, codec.chunk)
+                for j in range(m):
+                    acc3 = acc3 + ws[:, j, :, None] * c3[j]
+                acc = acc3.reshape(m, d_pad)
+                return acc.astype(dtype), ref, acc
 
         out, _, _ = jax.lax.fori_loop(0, t_server, one_round,
                                       (flat, zeros, zeros))
-        return _bucket_split(out, leaves, treedef)
+        with jax.named_scope("wire_pack"):
+            return _bucket_split(out, leaves, treedef)
 
     # ring of the last `staleness` in-flight (codes, scales) buffers; zero
     # codes + unit scales decode to nothing, so the pre-fill consumed
@@ -523,28 +527,32 @@ def gossip_scan_wire_bucketed(a: jax.Array, tree: Any, t_server: int,
         w, sref, acc, rc, rs = carry
         # produce round t: encode against the SENT reference, fold the own
         # decode in immediately (the next innovation must not re-ship it)
-        delta = w.astype(jnp.float32) - sref
-        dither = _bucket_dither_rows(codec, key, m, d_pad, rnd=t)
-        codes, scales = codec.encode_block(delta, dither)
-        own3 = codec.code_chunks(codes, d_pad)     # (m, nc, chunk)
-        sref = sref + (own3 * scales[..., None]).reshape(m, d_pad)
-        # consume round t - s: the oldest gathered buffer in the ring
-        old_c, old_s = rc[0], rs[0]
-        c3 = codec.code_chunks(old_c, d_pad)
-        ws = a32[:, :, None] * old_s               # (m, m, nc): ws[i, j]
-        acc3 = acc.reshape(m, -1, codec.chunk)
-        for j in range(m):
-            acc3 = acc3 + ws[:, j, :, None] * c3[j]
-        acc = acc3.reshape(m, d_pad)
-        rc = jnp.concatenate([rc[1:], codes[None]], axis=0)
-        rs = jnp.concatenate([rs[1:], scales[None]], axis=0)
-        # the iterate advances only once a delayed buffer has landed
-        w = jnp.where(t >= staleness, acc.astype(dtype), w)
+        with jax.named_scope("wire_encode"):
+            delta = w.astype(jnp.float32) - sref
+            dither = _bucket_dither_rows(codec, key, m, d_pad, rnd=t)
+            codes, scales = codec.encode_block(delta, dither)
+        with jax.named_scope("wire_decode_mix"):
+            own3 = codec.code_chunks(codes, d_pad)     # (m, nc, chunk)
+            sref = sref + (own3 * scales[..., None]).reshape(m, d_pad)
+            # consume round t - s: the oldest gathered buffer in the ring
+            old_c, old_s = rc[0], rs[0]
+            c3 = codec.code_chunks(old_c, d_pad)
+            ws = a32[:, :, None] * old_s               # (m, m, nc): ws[i, j]
+            acc3 = acc.reshape(m, -1, codec.chunk)
+            for j in range(m):
+                acc3 = acc3 + ws[:, j, :, None] * c3[j]
+            acc = acc3.reshape(m, d_pad)
+            # the iterate advances only once a delayed buffer has landed
+            w = jnp.where(t >= staleness, acc.astype(dtype), w)
+        with jax.named_scope("wire_gather"):
+            rc = jnp.concatenate([rc[1:], codes[None]], axis=0)
+            rs = jnp.concatenate([rs[1:], scales[None]], axis=0)
         return w, sref, acc, rc, rs
 
     out, _, _, _, _ = jax.lax.fori_loop(
         0, t_server, one_round_stale, (flat, zeros, zeros, ring_c, ring_s))
-    return _bucket_split(out, leaves, treedef)
+    with jax.named_scope("wire_pack"):
+        return _bucket_split(out, leaves, treedef)
 
 
 def bucketed_roundtrip_tree(codec, tree: Any,
@@ -949,14 +957,16 @@ def make_gossip_shard_map(mesh, t_server: int, leaf_specs: Any, *,
             # instead of the per-leaf form's (M, blk) reference matrix;
             # see ``gossip_scan_wire_bucketed`` for the telescoped
             # recursion and why acc_t == (A · R_t)_i exactly.
-            flat = jnp.concatenate(
-                [to_wire(leaf.astype(dtype)).reshape(-1)
-                 for leaf in leaves])
-            d_tot = flat.size
-            blk, nb = _compressors.bucket_block(d_tot, block, codec.chunk)
-            d_pad = nb * blk
-            if d_pad != d_tot:
-                flat = jnp.pad(flat, (0, d_pad - d_tot))
+            with jax.named_scope("wire_pack"):
+                flat = jnp.concatenate(
+                    [to_wire(leaf.astype(dtype)).reshape(-1)
+                     for leaf in leaves])
+                d_tot = flat.size
+                blk, nb = _compressors.bucket_block(d_tot, block,
+                                                    codec.chunk)
+                d_pad = nb * blk
+                if d_pad != d_tot:
+                    flat = jnp.pad(flat, (0, d_pad - d_tot))
 
             def encode_round(t, delta):
                 """Round-``t`` bucket encode under the shared dither
@@ -971,6 +981,27 @@ def make_gossip_shard_map(mesh, t_server: int, leaf_specs: Any, *,
                     dither = 0.5
                 return codec.encode_block(delta, dither)
 
+            def gather(codes, scales):
+                """Every server's round codes and chunk scales."""
+                with jax.named_scope("wire_gather"):
+                    if gather_codes:
+                        g_codes = jax.lax.all_gather(codes, axis_name)
+                    else:
+                        # simulated twin: the same code VALUES cross the
+                        # wire at full f32 width (the f32 -> int8
+                        # round-trip is exact on code integers), so the
+                        # collective moves 4x the bytes but the decode
+                        # still happens after the gather — keeping the
+                        # multiply-add structure, and therefore the FMA
+                        # contraction, identical to the physical program:
+                        # the two are asserted BITWISE equal, proving the
+                        # narrow wire changes encoding width only, never
+                        # the numerics
+                        g_codes = jax.lax.all_gather(
+                            codes.astype(jnp.float32),
+                            axis_name).astype(codes.dtype)
+                    return g_codes, jax.lax.all_gather(scales, axis_name)
+
             def round_fn_wire(t, carry):
                 """One bucketed quantized-wire round, delta-coded: encode
                 the innovation of my bucket against the receivers' shared
@@ -980,24 +1011,10 @@ def make_gossip_shard_map(mesh, t_server: int, leaf_specs: Any, *,
                 contracts with consensus, so per-hop quantization noise
                 vanishes instead of flooring (see ``gossip_scan_wire``)."""
                 w, ref, acc = carry            # (d_pad,) each
-                delta = from_wire(w).astype(jnp.float32) - ref
-                codes, scales = encode_round(t, delta)
-                if gather_codes:
-                    g_codes = jax.lax.all_gather(codes, axis_name)
-                else:
-                    # simulated twin: the same code VALUES cross the wire
-                    # at full f32 width (the f32 -> int8 round-trip is
-                    # exact on code integers), so the collective moves 4x
-                    # the bytes but the decode still happens after the
-                    # gather — keeping the multiply-add structure, and
-                    # therefore the FMA contraction, identical to the
-                    # physical program: the two are asserted BITWISE
-                    # equal, proving the narrow wire changes encoding
-                    # width only, never the numerics
-                    g_codes = jax.lax.all_gather(
-                        codes.astype(jnp.float32),
-                        axis_name).astype(codes.dtype)
-                g_scales = jax.lax.all_gather(scales, axis_name)
+                with jax.named_scope("wire_encode"):
+                    delta = from_wire(w).astype(jnp.float32) - ref
+                    codes, scales = encode_round(t, delta)
+                g_codes, g_scales = gather(codes, scales)
                 # Fused dequantize-and-mix: fold the per-chunk scales and
                 # the mixing-row weight into ONE broadcast factor per
                 # chunk, so the round never materialises the (M, d_pad)
@@ -1006,15 +1023,16 @@ def make_gossip_shard_map(mesh, t_server: int, leaf_specs: Any, *,
                 # Term order stays one server at a time, left to right,
                 # matching ``gossip_scan_wire_bucketed`` product for
                 # product (the oracle folds identically).
-                c3 = codec.code_chunks(g_codes, d_pad)   # (M, nc, chunk)
-                ref = ref + (c3[idx] * g_scales[idx][:, None]
-                             ).reshape(d_pad)
-                ws = row[:, None] * g_scales             # (M, nc) folded
-                acc3 = acc.reshape(-1, codec.chunk)
-                for j in range(m):
-                    acc3 = acc3 + ws[j][:, None] * c3[j]
-                acc = acc3.reshape(d_pad)
-                return to_wire(acc.astype(dtype)), ref, acc
+                with jax.named_scope("wire_decode_mix"):
+                    c3 = codec.code_chunks(g_codes, d_pad)  # (M, nc, chunk)
+                    ref = ref + (c3[idx] * g_scales[idx][:, None]
+                                 ).reshape(d_pad)
+                    ws = row[:, None] * g_scales            # (M, nc) folded
+                    acc3 = acc.reshape(-1, codec.chunk)
+                    for j in range(m):
+                        acc3 = acc3 + ws[j][:, None] * c3[j]
+                    acc = acc3.reshape(d_pad)
+                    return to_wire(acc.astype(dtype)), ref, acc
 
             def round_fn_wire_stale(t, carry):
                 """Software-pipelined bounded-staleness round: ISSUE round
@@ -1030,28 +1048,25 @@ def make_gossip_shard_map(mesh, t_server: int, leaf_specs: Any, *,
                 single-shipped; the iterate freezes until the first
                 delayed buffer lands (``t < staleness``)."""
                 w, ref, acc, rc, rs = carry
-                delta = from_wire(w).astype(jnp.float32) - ref
-                codes, scales = encode_round(t, delta)
-                if gather_codes:
-                    g_codes = jax.lax.all_gather(codes, axis_name)
-                else:
-                    g_codes = jax.lax.all_gather(
-                        codes.astype(jnp.float32),
-                        axis_name).astype(codes.dtype)
-                g_scales = jax.lax.all_gather(scales, axis_name)
-                own3 = codec.code_chunks(codes, d_pad)   # (nc, chunk)
-                ref = ref + (own3 * scales[:, None]).reshape(d_pad)
-                old_c, old_s = rc[0], rs[0]
-                c3 = codec.code_chunks(old_c, d_pad)     # (M, nc, chunk)
-                ws = row[:, None] * old_s                # (M, nc) folded
-                acc3 = acc.reshape(-1, codec.chunk)
-                for j in range(m):
-                    acc3 = acc3 + ws[j][:, None] * c3[j]
-                acc = acc3.reshape(d_pad)
-                rc = jnp.concatenate([rc[1:], g_codes[None]], axis=0)
-                rs = jnp.concatenate([rs[1:], g_scales[None]], axis=0)
-                w = jnp.where(t >= staleness,
-                              to_wire(acc.astype(dtype)), w)
+                with jax.named_scope("wire_encode"):
+                    delta = from_wire(w).astype(jnp.float32) - ref
+                    codes, scales = encode_round(t, delta)
+                g_codes, g_scales = gather(codes, scales)
+                with jax.named_scope("wire_decode_mix"):
+                    own3 = codec.code_chunks(codes, d_pad)  # (nc, chunk)
+                    ref = ref + (own3 * scales[:, None]).reshape(d_pad)
+                    old_c, old_s = rc[0], rs[0]
+                    c3 = codec.code_chunks(old_c, d_pad)    # (M, nc, chunk)
+                    ws = row[:, None] * old_s               # (M, nc) folded
+                    acc3 = acc.reshape(-1, codec.chunk)
+                    for j in range(m):
+                        acc3 = acc3 + ws[j][:, None] * c3[j]
+                    acc = acc3.reshape(d_pad)
+                    w = jnp.where(t >= staleness,
+                                  to_wire(acc.astype(dtype)), w)
+                with jax.named_scope("wire_gather"):
+                    rc = jnp.concatenate([rc[1:], g_codes[None]], axis=0)
+                    rs = jnp.concatenate([rs[1:], g_scales[None]], axis=0)
                 return w, ref, acc, rc, rs
 
             zeros = jnp.zeros((d_pad,), jnp.float32)
@@ -1067,9 +1082,10 @@ def make_gossip_shard_map(mesh, t_server: int, leaf_specs: Any, *,
                 # mixed output, single gather pair in the compiled HLO)
                 # and the pre-pass costs one encode instead of t_server
                 # bucket-sized selects.
-                codes0, scales0 = encode_round(
-                    0, from_wire(flat).astype(jnp.float32) - zeros)
-                shipped = codec.decode_block(codes0, scales0, d_pad)
+                with jax.named_scope("wire_encode"):
+                    codes0, scales0 = encode_round(
+                        0, from_wire(flat).astype(jnp.float32) - zeros)
+                    shipped = codec.decode_block(codes0, scales0, d_pad)
             else:
                 shipped = zeros
             if staleness == 0:
@@ -1089,16 +1105,17 @@ def make_gossip_shard_map(mesh, t_server: int, leaf_specs: Any, *,
                 w, _, _, _, _ = jax.lax.fori_loop(
                     0, t_server, round_fn_wire_stale,
                     (flat, zeros, zeros, ring_c, ring_s))
-            out = from_wire(w)
-            new_leaves, shipped_leaves, off = [], [], 0
-            for leaf in leaves:
-                size = leaf.size
-                new_leaves.append(out[off:off + size].astype(leaf.dtype)
-                                  .reshape(leaf.shape))
-                shipped_leaves.append(
-                    shipped[off:off + size].astype(leaf.dtype)
-                    .reshape(leaf.shape))
-                off += size
+            with jax.named_scope("wire_pack"):
+                out = from_wire(w)
+                new_leaves, shipped_leaves, off = [], [], 0
+                for leaf in leaves:
+                    size = leaf.size
+                    new_leaves.append(out[off:off + size].astype(leaf.dtype)
+                                      .reshape(leaf.shape))
+                    shipped_leaves.append(
+                        shipped[off:off + size].astype(leaf.dtype)
+                        .reshape(leaf.shape))
+                    off += size
             mixed = jax.tree.unflatten(treedef, new_leaves)
             if not with_shipped:
                 return mixed

@@ -408,8 +408,8 @@ def resolve_backend(cfg: "DFLConfig"):
     executes through: the injected ``cfg.consensus_backend`` if any, else
     one built from ``cfg.consensus_mode`` over the static topology matrix
     (``None`` for consensus_mode='none').  Shared by the epoch-step
-    builder and the engine's consensus-replay timing probe so both see
-    the SAME execution strategy."""
+    builder and ``active_compressor`` so both see the SAME execution
+    strategy."""
     topo = cfg.topology
     if cfg.consensus_backend is not None:
         return cfg.consensus_backend
@@ -568,9 +568,11 @@ def build_dfl_epoch_step(
                               params)
             grads, mlosses = jax.lax.scan(micro_step, g0, micro_batches)
             loss = mlosses.mean(axis=0)
-        params, opt_state = optimizer.update(grads, opt_state, params)
+        with jax.named_scope("sgd_update"):
+            params, opt_state = optimizer.update(grads, opt_state, params)
         if cfg.metrics == "full":
-            gnorm = jnp.sqrt(_tree_sq_norm(grads) / (m * n))
+            with jax.named_scope("epoch_metrics"):
+                gnorm = jnp.sqrt(_tree_sq_norm(grads) / (m * n))
         else:
             gnorm = jnp.zeros((), jnp.float32)
         return (params, opt_state, rng), (loss, gnorm)
@@ -615,37 +617,47 @@ def build_dfl_epoch_step(
         return backend.mix(server_tree, a_p, lam2=lam2), psum_weight, \
             ef_residual, screen0
 
+    def epoch_drift(params, start_params):
+        """Lemma-3 drift of each client from its start-of-epoch server
+        model (the broadcast client params at epoch entry)."""
+        if cfg.metrics != "full":
+            return jnp.zeros((), jnp.float32)
+        with jax.named_scope("epoch_metrics"):
+            return max_client_drift(
+                params, jax.tree.map(lambda x: x[:, 0], start_params))
+
+    def epoch_disagreement(server):
+        if cfg.metrics != "full":
+            return jnp.zeros((), jnp.float32)
+        with jax.named_scope("epoch_metrics"):
+            return disagreement_norm(server)
+
     def epoch_step(state: DFLState, batches: Any) -> Tuple[DFLState, DFLMetrics]:
         # ---- 1. local period: T_C client SGD iterations (Eq. 3) ----
         carry = (state.client_params, state.opt_state, state.rng)
-        (params, opt_state, rng), (losses, gnorms) = jax.lax.scan(
-            local_step, carry, batches)
-
-        # Lemma 3 LHS: drift of each client from its start-of-epoch server
-        # model w^i_p (== the broadcast client params at epoch entry).
-        if cfg.metrics == "full":
-            start_server = jax.tree.map(lambda x: x[:, 0],
-                                        state.client_params)
-            drift = max_client_drift(params, start_server)
-        else:
-            drift = jnp.zeros((), jnp.float32)
+        with jax.named_scope("local_period"):
+            (params, opt_state, rng), (losses, gnorms) = jax.lax.scan(
+                local_step, carry, batches)
 
         # ---- 2. aggregation at each server (Eq. 4) ----
-        server = server_mean(params)
+        with jax.named_scope("aggregate"):
+            server = server_mean(params)
+        drift = epoch_drift(params, state.client_params)
 
         # ---- 3. consensus period: T_S gossip rounds (Eq. 5/7) ----
         if compressed:
             rng, ckey = jax.random.split(rng)
         else:
             ckey = None
-        server, psw, ef_res, screen = apply_consensus(
-            server, psum_weight=state.psum_weight,
-            ef_residual=state.ef_residual, key=ckey)
-        disagreement = (disagreement_norm(server) if cfg.metrics == "full"
-                        else jnp.zeros((), jnp.float32))
+        with jax.named_scope("gossip_period"):
+            server, psw, ef_res, screen = apply_consensus(
+                server, psum_weight=state.psum_weight,
+                ef_residual=state.ef_residual, key=ckey)
+        disagreement = epoch_disagreement(server)
 
         # ---- 4. broadcast w^i_p back to C_i ----
-        params = broadcast_to_clients(server, n)
+        with jax.named_scope("broadcast"):
+            params = broadcast_to_clients(server, n)
 
         new_state = DFLState(params, opt_state, state.epoch + 1, rng, psw,
                              ef_res)
@@ -665,22 +677,17 @@ def build_dfl_epoch_step(
         # applied afterwards, which is mathematically identical (clients are
         # independent during the local period) and keeps the scan dense.
         carry = (state.client_params, state.opt_state, state.rng)
-        (params, opt_state, rng), (losses, gnorms) = jax.lax.scan(
-            local_step, carry, batches)
-        # non-participants carry their broadcast model (and optimizer state)
-        # through the epoch untouched
-        params = carry_forward(mask, params, state.client_params)
-        opt_state = carry_forward(mask, opt_state, state.opt_state)
-
-        if cfg.metrics == "full":
-            start_server = jax.tree.map(lambda x: x[:, 0],
-                                        state.client_params)
-            drift = max_client_drift(params, start_server)
-        else:
-            drift = jnp.zeros((), jnp.float32)
-
-        # ---- 2. masked aggregation (Eq. 4 over the participating set) ----
-        server = masked_server_mean(params, mask)
+        with jax.named_scope("local_period"):
+            (params, opt_state, rng), (losses, gnorms) = jax.lax.scan(
+                local_step, carry, batches)
+        # ---- 2. masked aggregation (Eq. 4 over the participating set);
+        # non-participants carry their broadcast model (and optimizer
+        # state) through the epoch untouched ----
+        with jax.named_scope("aggregate"):
+            params = carry_forward(mask, params, state.client_params)
+            opt_state = carry_forward(mask, opt_state, state.opt_state)
+            server = masked_server_mean(params, mask)
+        drift = epoch_drift(params, state.client_params)
 
         # ---- 2b. adversarial injection: marked servers replace their
         # aggregate BEFORE gossip — this is the message the federation
@@ -695,14 +702,15 @@ def build_dfl_epoch_step(
             rng, ckey = jax.random.split(rng)
         else:
             ckey = None
-        server, psw, ef_res, screen = apply_consensus(
-            server, a_p, psum_weight=state.psum_weight,
-            ef_residual=state.ef_residual, key=ckey, lam2=lam2)
-        disagreement = (disagreement_norm(server) if cfg.metrics == "full"
-                        else jnp.zeros((), jnp.float32))
+        with jax.named_scope("gossip_period"):
+            server, psw, ef_res, screen = apply_consensus(
+                server, a_p, psum_weight=state.psum_weight,
+                ef_residual=state.ef_residual, key=ckey, lam2=lam2)
+        disagreement = epoch_disagreement(server)
 
         # ---- 4. broadcast (every client, participant or not) ----
-        params = broadcast_to_clients(server, n)
+        with jax.named_scope("broadcast"):
+            params = broadcast_to_clients(server, n)
 
         new_state = DFLState(params, opt_state, state.epoch + 1, rng, psw,
                              ef_res)
@@ -712,59 +720,6 @@ def build_dfl_epoch_step(
         return new_state, metrics
 
     return epoch_step_dynamic if cfg.dynamic else epoch_step
-
-
-def build_consensus_replay(cfg: DFLConfig) -> Optional[Callable]:
-    """A consensus-period-only program for WALL-CLOCK ATTRIBUTION.
-
-    ``replay(server_tree, a_p, lam2) -> mixed_tree`` re-runs just the
-    T_S-round consensus period — the same ``ConsensusBackend``
-    (``resolve_backend``), mixing interpretation, and compression wrapper
-    as the full epoch step — on an already-computed server tree.  The
-    engine's span tracer times it (results DISCARDED, nothing donated)
-    to split one compiled epoch step's wall time into local-period vs
-    gossip-period estimates: the two phases cannot be timed separately
-    inside one compiled program without a host sync in the middle, which
-    would change the very schedule being measured.
-
-    The replay is an estimate, not the in-program truth — XLA may overlap
-    phases differently in the fused step (exactly what the ROADMAP's
-    overlapped-consensus work will exploit); spans carry
-    ``method="consensus-replay"`` to say so.  Under compressed consensus
-    the probe uses a fixed rounding key and a zero EF residual: timing
-    only — its numerics never touch training state.  Returns ``None``
-    when there is no consensus period to time (M == 1, T_S == 0, or
-    consensus_mode='none')."""
-    topo = cfg.topology
-    m = topo.num_servers
-    if m == 1 or topo.t_server == 0:
-        return None
-    backend = resolve_backend(cfg)
-    if backend is None:
-        return None
-    compressed = getattr(backend, "compressed", False)
-    ef = wants_error_feedback(cfg)
-
-    def replay(server_tree: Any, a_p: jax.Array,
-               lam2: Optional[jax.Array] = None) -> Any:
-        key = jax.random.key(0) if compressed else None
-        residual = (jax.tree.map(jnp.zeros_like, server_tree)
-                    if compressed and ef else None)
-        if cfg.mixing == "push_sum":
-            ps0 = cns.init_push_sum(server_tree)
-            if compressed:
-                ps, _ = backend.mix_push_sum_compressed(
-                    ps0, a_p, residual=residual, key=key)
-            else:
-                ps = backend.mix_push_sum(ps0, a_p)
-            return ps.ratio()
-        if compressed:
-            mixed, _ = backend.mix_compressed(
-                server_tree, a_p, residual=residual, key=key, lam2=lam2)
-            return mixed
-        return backend.mix(server_tree, a_p, lam2=lam2)
-
-    return replay
 
 
 def init_dfl_state(cfg: DFLConfig, params: Any, optimizer: Optimizer,

@@ -74,9 +74,9 @@ class DynamicFederationEngine:
     # observability bundle (repro.obs.Observability) or None for the no-op
     # null bundle.  HARD CONTRACT: attaching one is bitwise inert on
     # training numerics — the instrumentation only reads already-computed
-    # values, the compiled programs are identical with obs on or off
-    # (asserted in tests/test_obs.py), and the tracer's block_until_ready
-    # sync points exist only when a tracer is attached.
+    # values and the compiled programs are identical with obs on or off
+    # (asserted in tests/test_obs.py).  Either way every host phase below
+    # is a jax.profiler annotation (``obs.span``).
     obs: Any = None
     # superepoch length K: run() dispatches K epochs per compiled program
     # (overlap.build_dfl_superepoch_step) and reads K epochs of metrics
@@ -147,15 +147,6 @@ class DynamicFederationEngine:
                                     wire=dfl.active_wire(self.cfg)[0])
                        if self._compressor is not None else None)
         self._row_bytes: Dict[int, Tuple[int, int]] = {}  # M -> (bytes, elems)
-        # consensus-replay timing probes (dfl.build_consensus_replay),
-        # built lazily per M and ONLY when a span tracer is attached
-        self._probes: Dict[int, Optional[Callable]] = {}
-        self._probe_warm: set = set()
-        # one-time per-M gossip-period wall-time calibration (ns), measured
-        # by timing the consensus-replay probe ONCE per federation size —
-        # superepoch spans attribute per-epoch/per-round from this instead
-        # of re-executing the probe every epoch
-        self._probe_cal: Dict[int, Optional[int]] = {}
         # spectral backends (chebyshev) consume a host-side per-epoch
         # |lambda_2(A_p)| alongside the traced matrix
         backend = self.cfg.consensus_backend
@@ -370,191 +361,111 @@ class DynamicFederationEngine:
         return state
 
     # -- observability -------------------------------------------------------
-    def _consensus_probe(self, m: int) -> Optional[Callable]:
-        """The jitted consensus-replay timing probe for federation size
-        ``m`` (``dfl.build_consensus_replay``), or None when there is no
-        consensus period to time.  Built lazily, and only ever reached
-        when a span tracer is attached."""
-        if m not in self._probes:
-            cfg = dataclasses.replace(self.cfg, topology=self.topo)
-            fn = dfl.build_consensus_replay(cfg)
-            self._probes[m] = None if fn is None else jax.jit(fn)
-        return self._probes[m]
-
-    def _trace_step(self, epoch_span, epoch: int, m: int, m_known: bool,
-                    programs_before: int, t0: int, t1: int,
-                    state: dfl.DFLState, a_np, lam2) -> None:
-        """Tracer-only post-step work: emit the compile event if this call
-        traced a new program, then split the step's [t0, t1] wall interval
-        into local-period / gossip-period spans via the consensus-replay
-        probe (re-run the consensus period alone on the post-epoch server
-        tree, warmed once per M untimed; its wall time estimates the
-        gossip share of the fused step)."""
-        tracer = self.obs.tracer
-        programs_after = int(self._steps[m]._cache_size())
-        if programs_after > programs_before:
-            if not m_known and len(self._steps) == 1:
-                cause = "first_trace"
-            elif not m_known:
-                cause = "federation_size_change"
-            else:
-                # a schedule operand leaked into trace structure — the
-                # compile-once contract (compile_counts) is being violated
-                cause = "retrace"
-            tracer.compile_event(cause, m=m, programs=programs_after,
-                                 epoch=epoch)
-        probe = self._consensus_probe(m)
-        if probe is None:
-            tracer.add_span("local-period", t0, t1, parent=epoch_span,
-                            epoch=epoch)
+    def _compile_event(self, steps: Dict[Any, Callable], key: Any,
+                       known: bool, programs_before: int, **args: Any
+                       ) -> None:
+        """Emit the ``compile`` event (an attached tracer records it) if
+        the dispatch of the cached step ``steps[key]`` just traced a new
+        program, with its cause."""
+        if steps[key]._cache_size() <= programs_before:
             return
-        server_tree = jax.tree.map(lambda x: x[:, 0], state.client_params)
-        a_j = jnp.asarray(a_np, jnp.float32)
-        if m not in self._probe_warm:
-            jax.block_until_ready(probe(server_tree, a_j, lam2))
-            self._probe_warm.add(m)
-        p0 = tracer.now()
-        jax.block_until_ready(probe(server_tree, a_j, lam2))
-        gossip_ns = min(tracer.now() - p0, t1 - t0)
-        split = t1 - gossip_ns
-        tracer.add_span("local-period", t0, split, parent=epoch_span,
-                        epoch=epoch, method="consensus-replay")
-        tracer.add_span("gossip-period", split, t1, parent=epoch_span,
-                        epoch=epoch, method="consensus-replay",
-                        t_server=self.topo.t_server)
+        if not known and len(steps) == 1:
+            cause = "first_trace"
+        elif not known:
+            cause = "federation_size_change"
+        else:
+            # a schedule operand leaked into trace structure — the
+            # compile-once contract (compile_counts) is being violated
+            cause = "retrace"
+        self.obs.compile_event(cause, m=self.topo.num_servers,
+                               programs=int(steps[key]._cache_size()),
+                               **args)
 
-    def _gossip_cal_ns(self, m: int, state: dfl.DFLState, a_np,
-                       lam2) -> Optional[int]:
-        """ONE-TIME per-M calibration of the gossip-period wall share: time
-        the consensus-replay probe once (after an untimed warm-up) and
-        cache the result.  Superepoch span attribution reuses this number
-        for every epoch of every block at this M instead of re-executing
-        the probe per epoch — K probe re-executions per block would cost
-        more wall time than the barrier they replace.  ``None`` when there
-        is no consensus period to time."""
-        if m not in self._probe_cal:
-            probe = self._consensus_probe(m)
-            if probe is None:
-                self._probe_cal[m] = None
-            else:
-                tracer = self.obs.tracer
-                server_tree = jax.tree.map(lambda x: x[:, 0],
-                                           state.client_params)
-                a_j = jnp.asarray(a_np, jnp.float32)
-                jax.block_until_ready(probe(server_tree, a_j, lam2))
-                p0 = tracer.now()
-                jax.block_until_ready(probe(server_tree, a_j, lam2))
-                self._probe_cal[m] = int(tracer.now() - p0)
-        return self._probe_cal[m]
+    def _schedule(self, epoch: int):
+        """Host-side operands of one epoch at the current federation size:
+        ``(mask, mixing, lam2, byzantine codes)`` as numpy (lam2 and the
+        codes None where unused)."""
+        m, n = self.topo.num_servers, self.topo.clients_per_server
+        mask_np = self.participation.mask(epoch, m, n)
+        a_np = self.topology_schedule.mixing(self.topo, epoch)
+        lam2 = (np.float32(tp.lambda_2(a_np)) if self._needs_spectral
+                else None)
+        byz_np = None
+        if self.cfg.byzantine is not None and self.cfg.byzantine.attacks:
+            # per-row attack codes over the CURRENT federation: original
+            # attacker ids (stable across surgery — drawn over the
+            # ORIGINAL size) mapped through the alive row order.  The
+            # array is passed every epoch, all-zero included, so the
+            # compiled step's operand structure never changes.
+            byz_np = self.cfg.byzantine.codes(epoch, tuple(self.alive),
+                                              self._initial_m)
+        return mask_np, a_np, lam2, byz_np
 
-    def _trace_superepoch(self, se_span, epoch0: int, k: int, m: int,
-                          m_known: bool, programs_before: int, t0: int,
-                          t1: int, state: dfl.DFLState, a_np, lam2) -> None:
-        """Tracer-only post-dispatch attribution of one fused K-epoch
-        megastep: compile event if this dispatch traced a new program, then
-        the [t0, t1] wall interval split uniformly into K per-epoch spans,
-        each split into local-period / gossip-period via the cached
-        ``_gossip_cal_ns`` calibration, and the gossip period further into
-        T_S equal ``gossip-round`` child spans (``method=
-        "calibrated-round"`` — attribution, not per-round measurement:
-        rounds cannot be timed individually inside one compiled program
-        without host syncs that would destroy the very overlap being
-        measured)."""
-        tracer = self.obs.tracer
-        programs_after = int(self._super_steps[(m, k)]._cache_size())
-        if programs_after > programs_before:
-            if not m_known and len(self._super_steps) == 1:
-                cause = "first_trace"
-            elif not m_known:
-                cause = "federation_size_change"
-            else:
-                cause = "retrace"
-            tracer.compile_event(cause, m=m, programs=programs_after,
-                                 epoch=epoch0, superepoch=k)
-        gossip_ns = self._gossip_cal_ns(m, state, a_np, lam2)
-        t_server = self.topo.t_server
-        dt = max((t1 - t0) // k, 1)
-        for i in range(k):
-            e0 = min(t0 + i * dt, t1)
-            e1 = t1 if i == k - 1 else min(t0 + (i + 1) * dt, t1)
-            ep_span = tracer.add_span("epoch", e0, e1, parent=se_span,
-                                      epoch=epoch0 + i,
-                                      method="uniform-split")
-            if gossip_ns is None:
-                tracer.add_span("local-period", e0, e1, parent=ep_span,
-                                epoch=epoch0 + i)
-                continue
-            g = min(gossip_ns, e1 - e0)
-            split = e1 - g
-            tracer.add_span("local-period", e0, split, parent=ep_span,
-                            epoch=epoch0 + i, method="calibrated")
-            gp = tracer.add_span("gossip-period", split, e1, parent=ep_span,
-                                 epoch=epoch0 + i, method="calibrated",
-                                 t_server=t_server)
-            rdt = max(g // max(t_server, 1), 1)
-            for r in range(t_server):
-                r0 = min(split + r * rdt, e1)
-                r1 = e1 if r == t_server - 1 else min(split + (r + 1) * rdt,
-                                                      e1)
-                tracer.add_span("gossip-round", r0, r1, parent=gp,
-                                epoch=epoch0 + i, round=r,
-                                method="calibrated-round")
+    @staticmethod
+    def _epoch_schedule(mask_np, a_np, lam2, byz_np) -> EpochSchedule:
+        """The traced ``EpochSchedule`` operand of the epoch step."""
+        return EpochSchedule(jnp.asarray(mask_np, jnp.float32),
+                             jnp.asarray(a_np, jnp.float32),
+                             None if lam2 is None else jnp.float32(lam2),
+                             None if byz_np is None
+                             else jnp.asarray(byz_np, jnp.int32))
+
+    def epoch_program(self, state: dfl.DFLState, epoch: int,
+                      batch_fn: BatchFn) -> jax.stages.Compiled:
+        """The compiled epoch program at the current federation size,
+        lowered from the operands ``run_epoch(state, epoch, batch_fn)``
+        would pass (nothing runs, nothing is donated).  Its ``as_text()``
+        names the ops a device trace of the program shows, with the
+        ``jax.named_scope`` phases on each op's metadata, and its
+        ``memory_analysis()`` gives the program's bytes.  The program is
+        the one ``run_epoch`` runs, so after an epoch has run this
+        compiles nothing new."""
+        sched = self._epoch_schedule(*self._schedule(epoch))
+        batches = self._place(batch_fn(epoch, tuple(self.alive)), 0)
+        return self._step().lower(self._to_step(state), batches,
+                                  sched).compile()
 
     # -- the loop ------------------------------------------------------------
     def run_epoch(self, state: dfl.DFLState, epoch: int,
                   batch_fn: BatchFn) -> Tuple[dfl.DFLState, Dict[str, float]]:
+        """One epoch: fault surgery, the host schedule, the batch, the
+        compiled epoch step, ONE read-back of its metrics, the record.
+        Each phase is an ``obs.span`` (a profiler annotation), so a device
+        trace shows what the host was doing while the chip idled."""
         obs = self.obs
-        tracer = obs.tracer
-        with obs.span("epoch", epoch=epoch) as epoch_span:
+        with obs.span("epoch", epoch=epoch):
             with obs.span("fault-surgery", epoch=epoch):
                 state = self.apply_faults(state, epoch)
-            m, n = self.topo.num_servers, self.topo.clients_per_server
-            mask_np = self.participation.mask(epoch, m, n)
-            a_np = self.topology_schedule.mixing(self.topo, epoch)
-            sigma_prod = self._tracker.update(a_np, self.topo.t_server)
-            batches = self._place(batch_fn(epoch, tuple(self.alive)), 0)
-            lam2 = (jnp.float32(tp.lambda_2(a_np)) if self._needs_spectral
-                    else None)
-            byz_np = None
-            if self.cfg.byzantine is not None and self.cfg.byzantine.attacks:
-                # per-row attack codes over the CURRENT federation: original
-                # attacker ids (stable across surgery — drawn over the
-                # ORIGINAL size) mapped through the alive row order.  The
-                # array is passed every epoch, all-zero included, so the
-                # compiled step's operand structure never changes.
-                byz_np = self.cfg.byzantine.codes(epoch, tuple(self.alive),
-                                                  self._initial_m)
-            sched = EpochSchedule(jnp.asarray(mask_np, jnp.float32),
-                                  jnp.asarray(a_np, jnp.float32), lam2,
-                                  None if byz_np is None
-                                  else jnp.asarray(byz_np, jnp.int32))
-            epoch_wire_bytes = None
-            if self._bytes is not None:
-                row_bytes, elems = self._wire_row_bytes(state)
-                epoch_wire_bytes = self._bytes.update(
-                    a_np, self.topo.t_server, row_bytes=row_bytes,
-                    elems_per_row=elems)
-            m_known = m in self._steps
-            step = self._step()
-            # the tracer's sync point lives strictly OUTSIDE the compiled
-            # program and exists ONLY when a tracer is attached; the
-            # untraced path dispatches exactly as before
-            programs_before = int(step._cache_size()) if tracer else 0
-            t0 = tracer.now() if tracer else 0
-            state, metrics = step(self._to_step(state), batches, sched)
-            state = self._from_step(state)
-            if tracer is not None:
-                jax.block_until_ready(state)
-                self._trace_step(epoch_span, epoch, m, m_known,
-                                 programs_before, t0, tracer.now(), state,
-                                 a_np, lam2)
-            with obs.span("host-aggregation", epoch=epoch):
+            with obs.span("schedule", epoch=epoch):
+                m = self.topo.num_servers
+                mask_np, a_np, lam2, byz_np = self._schedule(epoch)
+                sigma_prod = self._tracker.update(a_np, self.topo.t_server)
+                sched = self._epoch_schedule(mask_np, a_np, lam2, byz_np)
+                epoch_wire_bytes = None
+                if self._bytes is not None:
+                    row_bytes, elems = self._wire_row_bytes(state)
+                    epoch_wire_bytes = self._bytes.update(
+                        a_np, self.topo.t_server, row_bytes=row_bytes,
+                        elems_per_row=elems)
+            with obs.span("batch", epoch=epoch):
+                batches = self._place(batch_fn(epoch, tuple(self.alive)), 0)
+            with obs.span("dispatch", epoch=epoch):
+                # enqueues the program; the host waits in ``readback``
+                m_known = m in self._steps
+                step = self._step()
+                programs_before = step._cache_size()
+                state, metrics = step(self._to_step(state), batches, sched)
+                state = self._from_step(state)
+            self._compile_event(self._steps, m, m_known, programs_before,
+                                epoch=epoch)
+            with obs.span("readback", epoch=epoch):
                 # ONE device->host transfer for the whole metrics struct:
                 # the old per-field float(...)/np.asarray reads each issued
                 # their own blocking transfer (5 syncs per epoch on the
                 # push-sum + screen path) — everything below is numpy
                 metrics_h, psw_h = self._device_get(
                     (metrics, state.psum_weight))
+            with obs.span("host-aggregation", epoch=epoch):
                 # participant-weighted loss of the last local iteration
                 last = np.asarray(metrics_h.loss[-1], np.float32)
                 w = mask_np if mask_np.sum() else np.ones_like(mask_np)
@@ -597,11 +508,11 @@ class DynamicFederationEngine:
                         / rounds)
                     record["screen_rejected"] = float(
                         screen_per_round.sum())
-            obs.observe(
-                epoch, record, servers=tuple(self.alive),
-                per_link=(self._bytes.per_link
-                          if self._bytes is not None else None),
-                screen_rejected=screen_per_round)
+                obs.observe(
+                    epoch, record, servers=tuple(self.alive),
+                    per_link=(self._bytes.per_link
+                              if self._bytes is not None else None),
+                    screen_rejected=screen_per_round)
         return state, record
 
     # -- superepoch dispatch -------------------------------------------------
@@ -640,64 +551,51 @@ class DynamicFederationEngine:
         arrays, so ``run(superepoch=K)`` history is element-identical to
         the barrier loop's (``tests/test_overlap.py``)."""
         obs = self.obs
-        tracer = obs.tracer
-        with obs.span("superepoch", epoch=epoch0, k=k) as se_span:
+        with obs.span("superepoch", epoch=epoch0, k=k):
             with obs.span("fault-surgery", epoch=epoch0):
                 state = self.apply_faults(state, epoch0)
-            m, n = self.topo.num_servers, self.topo.clients_per_server
+            m = self.topo.num_servers
             # pre-materialize the block: one host-side pass per epoch, no
             # device work — the schedules are plain numpy until stacked
-            scheds: List[EpochSchedule] = []
-            batch_list: List[Any] = []
-            sigma_list: List[float] = []
-            lam2_last = None
-            for i in range(k):
-                e = epoch0 + i
-                mask_np = self.participation.mask(e, m, n)
-                a_np = self.topology_schedule.mixing(self.topo, e)
-                sigma_list.append(self._tracker.update(a_np,
-                                                       self.topo.t_server))
-                lam2 = (np.float32(tp.lambda_2(a_np))
-                        if self._needs_spectral else None)
-                lam2_last = lam2
-                byz_np = None
-                if (self.cfg.byzantine is not None
-                        and self.cfg.byzantine.attacks):
-                    byz_np = self.cfg.byzantine.codes(
-                        e, tuple(self.alive), self._initial_m)
-                scheds.append(EpochSchedule(mask_np, a_np, lam2, byz_np))
-                batch_list.append(batch_fn(e, tuple(self.alive)))
-            sb = overlap.stack_epoch_schedules(scheds)
-            sched = overlap.EpochScheduleBatch(
-                jnp.asarray(sb.mask), jnp.asarray(sb.mixing),
-                None if sb.lam2 is None else jnp.asarray(sb.lam2),
-                None if sb.byz is None else jnp.asarray(sb.byz))
-            batches = self._place(
-                jax.tree.map(lambda *xs: jnp.stack(xs), *batch_list), 1)
-            wire = None
-            if self._bytes is not None:
-                row_bytes, elems = self._wire_row_bytes(state)
-                wire = self._bytes.update_many(
-                    [s.mixing for s in scheds], self.topo.t_server,
-                    row_bytes=row_bytes, elems_per_row=elems)
-            m_known = (m, k) in self._super_steps
-            step = self._super_step(k)
-            programs_before = int(step._cache_size()) if tracer else 0
-            t0 = tracer.now() if tracer else 0
-            state, metrics, psw = step(self._to_step(state), batches,
-                                       sched)
-            state = self._from_step(state)
-            if tracer is not None:
-                jax.block_until_ready(state)
-                self._trace_superepoch(
-                    se_span, epoch0, k, m, m_known, programs_before, t0,
-                    tracer.now(), state, scheds[-1].mixing,
-                    None if lam2_last is None else jnp.float32(lam2_last))
-            records: List[Tuple[Dict[str, float], Optional[np.ndarray]]] = []
-            with obs.span("host-aggregation", epoch=epoch0, k=k):
+            with obs.span("schedule", epoch=epoch0, k=k):
+                scheds: List[EpochSchedule] = []
+                sigma_list: List[float] = []
+                for i in range(k):
+                    sched_np = EpochSchedule(*self._schedule(epoch0 + i))
+                    sigma_list.append(self._tracker.update(
+                        sched_np.mixing, self.topo.t_server))
+                    scheds.append(sched_np)
+                sb = overlap.stack_epoch_schedules(scheds)
+                sched = overlap.EpochScheduleBatch(
+                    jnp.asarray(sb.mask), jnp.asarray(sb.mixing),
+                    None if sb.lam2 is None else jnp.asarray(sb.lam2),
+                    None if sb.byz is None else jnp.asarray(sb.byz))
+                wire = None
+                if self._bytes is not None:
+                    row_bytes, elems = self._wire_row_bytes(state)
+                    wire = self._bytes.update_many(
+                        [s.mixing for s in scheds], self.topo.t_server,
+                        row_bytes=row_bytes, elems_per_row=elems)
+            with obs.span("batch", epoch=epoch0, k=k):
+                batch_list = [batch_fn(epoch0 + i, tuple(self.alive))
+                              for i in range(k)]
+                batches = self._place(
+                    jax.tree.map(lambda *xs: jnp.stack(xs), *batch_list), 1)
+            with obs.span("dispatch", epoch=epoch0, k=k):
+                m_known = (m, k) in self._super_steps
+                step = self._super_step(k)
+                programs_before = step._cache_size()
+                state, metrics, psw = step(self._to_step(state), batches,
+                                           sched)
+                state = self._from_step(state)
+            self._compile_event(self._super_steps, (m, k), m_known,
+                                programs_before, epoch=epoch0, superepoch=k)
+            with obs.span("readback", epoch=epoch0, k=k):
                 # the block's ONLY device->host transfer: K epochs of
                 # stacked metrics + the (K, M) push-sum weight trace
                 metrics_h, psw_h = self._device_get((metrics, psw))
+            records: List[Tuple[Dict[str, float], Optional[np.ndarray]]] = []
+            with obs.span("host-aggregation", epoch=epoch0, k=k):
                 rounds = max(self.topo.t_server, 1)
                 for i in range(k):
                     mask_np = scheds[i].mask
@@ -731,11 +629,11 @@ class DynamicFederationEngine:
                         record["screen_rejected"] = float(
                             screen_per_round.sum())
                     records.append((record, screen_per_round))
-            for i, (record, screen_per_round) in enumerate(records):
-                obs.observe(
-                    epoch0 + i, record, servers=tuple(self.alive),
-                    per_link=(wire[i][2] if wire is not None else None),
-                    screen_rejected=screen_per_round)
+                for i, (record, screen_per_round) in enumerate(records):
+                    obs.observe(
+                        epoch0 + i, record, servers=tuple(self.alive),
+                        per_link=(wire[i][2] if wire is not None else None),
+                        screen_rejected=screen_per_round)
         return state, [r for r, _ in records]
 
     def run(self, state: dfl.DFLState, epochs: int,

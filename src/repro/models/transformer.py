@@ -133,43 +133,50 @@ def block_apply(params: Dict, x: jax.Array, cfg: ArchConfig, kind: str,
                 is_moe: bool, *, memory: Optional[jax.Array] = None,
                 opts: ApplyOptions = DEFAULT_OPTS,
                 causal: bool = True) -> Tuple[jax.Array, jax.Array]:
-    """Full-sequence block. Returns (x, aux_loss)."""
+    """Full-sequence block. Returns (x, aux_loss).  The sequence mixer
+    (attention, or a Mamba mixer) with its norm and residual runs under the
+    ``attention`` named scope and the FFN sublayer under ``mlp``, so a
+    device trace of the compiled program attributes each op to one."""
     aux = jnp.zeros((), jnp.float32)
-    h = nn.rmsnorm_apply(params["ln1"], x, cfg.norm_eps)
-    if kind == "mamba":
-        mix = mamba_mod.mamba_apply(params["mixer"], h, cfg,
-                                    impl=opts.attn_impl
-                                    if opts.attn_impl == "pallas" else "reference",
-                                    chunk_override=opts.ssd_chunk,
-                                    head_sharding=opts.ssd_head_sharding)
-    elif cfg.mla is not None:
-        mix = nn.mla_apply(params["mixer"], h, cfg,
-                           head_sharding=opts.attn_head_sharding)
-    else:
-        mix = nn.attention_apply(params["mixer"], h, cfg, layer_kind=kind,
-                                 causal=causal, attn_impl=opts.attn_impl,
-                                 head_sharding=opts.attn_head_sharding)
-    if "post_ln1" in params:
-        mix = nn.rmsnorm_apply(params["post_ln1"], mix, cfg.norm_eps)
-    x = x + mix
+    with jax.named_scope("attention"):
+        h = nn.rmsnorm_apply(params["ln1"], x, cfg.norm_eps)
+        if kind == "mamba":
+            mix = mamba_mod.mamba_apply(
+                params["mixer"], h, cfg,
+                impl=opts.attn_impl
+                if opts.attn_impl == "pallas" else "reference",
+                chunk_override=opts.ssd_chunk,
+                head_sharding=opts.ssd_head_sharding)
+        elif cfg.mla is not None:
+            mix = nn.mla_apply(params["mixer"], h, cfg,
+                               head_sharding=opts.attn_head_sharding)
+        else:
+            mix = nn.attention_apply(params["mixer"], h, cfg,
+                                     layer_kind=kind, causal=causal,
+                                     attn_impl=opts.attn_impl,
+                                     head_sharding=opts.attn_head_sharding)
+        if "post_ln1" in params:
+            mix = nn.rmsnorm_apply(params["post_ln1"], mix, cfg.norm_eps)
+        x = x + mix
     if memory is not None and "cross_attn" in params:
         h = nn.rmsnorm_apply(params["cross_ln"], x, cfg.norm_eps)
         mem_mask = jnp.ones((x.shape[1], memory.shape[1]), bool)
         x = x + nn.attention_apply(params["cross_attn"], h, cfg,
                                    kv_override=(memory, mem_mask))
     if "ffn" in params:
-        h = nn.rmsnorm_apply(params["ln2"], x, cfg.norm_eps)
-        if is_moe:
-            ff, aux = nn.moe_apply(params["ffn"], h, cfg,
-                                   capacity_factor=opts.capacity_factor,
-                                   no_drop=opts.moe_no_drop,
-                                   groups=opts.moe_groups,
-                                   group_sharding=opts.moe_group_sharding)
-        else:
-            ff = nn.mlp_apply(params["ffn"], h, cfg.act)
-        if "post_ln2" in params:
-            ff = nn.rmsnorm_apply(params["post_ln2"], ff, cfg.norm_eps)
-        x = x + ff
+        with jax.named_scope("mlp"):
+            h = nn.rmsnorm_apply(params["ln2"], x, cfg.norm_eps)
+            if is_moe:
+                ff, aux = nn.moe_apply(params["ffn"], h, cfg,
+                                       capacity_factor=opts.capacity_factor,
+                                       no_drop=opts.moe_no_drop,
+                                       groups=opts.moe_groups,
+                                       group_sharding=opts.moe_group_sharding)
+            else:
+                ff = nn.mlp_apply(params["ffn"], h, cfg.act)
+            if "post_ln2" in params:
+                ff = nn.rmsnorm_apply(params["post_ln2"], ff, cfg.norm_eps)
+            x = x + ff
     return x, aux
 
 
@@ -239,9 +246,10 @@ def init_params(key, cfg: ArchConfig, dtype=jnp.float32) -> Dict:
 
 
 def _embed(params, cfg: ArchConfig, tokens: jax.Array) -> jax.Array:
-    x = params["embed"][tokens]
-    if cfg.final_logit_softcap is not None:  # gemma family scales embeddings
-        x = x * jnp.asarray(math.sqrt(cfg.d_model), x.dtype)
+    with jax.named_scope("embed"):
+        x = params["embed"][tokens]
+        if cfg.final_logit_softcap is not None:  # gemma scales embeddings
+            x = x * jnp.asarray(math.sqrt(cfg.d_model), x.dtype)
     return x
 
 
@@ -354,11 +362,19 @@ def make_loss_fn(cfg: ArchConfig, opts: ApplyOptions = DEFAULT_OPTS,
       partially reduces per shard (small (b, chunk) all-reduce), and the
       target logit is a one-hot contraction instead of take_along_axis
       (whose gather would force a full-vocab all-gather).
+
+    The head and the cross-entropy run under the ``lm_head`` named scope.
     """
 
     def loss_fn(params, batch, rng):
         del rng
         x, aux = forward_hidden(params, cfg, batch, opts=opts)
+        with jax.named_scope("lm_head"):
+            nll_mean = chunked_nll(params, batch, x)
+        loss = nll_mean + aux
+        return loss, {"nll": nll_mean, "aux": aux}
+
+    def chunked_nll(params, batch, x):
         xs = x[:, :-1]                                       # predict t+1
         targets = batch["tokens"][:, 1:]
         b, sm1, d = xs.shape
@@ -386,9 +402,7 @@ def make_loss_fn(cfg: ArchConfig, opts: ApplyOptions = DEFAULT_OPTS,
 
         total, _ = jax.lax.scan(jax.checkpoint(body),
                                 jnp.zeros((), jnp.float32), (xc, tc))
-        nll_mean = total / (b * sm1)
-        loss = nll_mean + aux
-        return loss, {"nll": nll_mean, "aux": aux}
+        return total / (b * sm1)
 
     return loss_fn
 

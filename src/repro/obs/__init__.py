@@ -3,12 +3,19 @@
 Three layers, combinable through one ``Observability`` bundle handed to
 ``DynamicFederationEngine`` / the trainers:
 
-- ``trace.Tracer``            — host-side span tracing -> Chrome trace
+- ``trace.Tracer``            — host-side span recording -> Chrome trace
                                 JSON (Perfetto-loadable).
 - ``metrics.MetricsHub``      — typed counter/gauge/histogram events
                                 fanned out to Memory/JSONL/Console sinks.
 - ``monitor.ConvergenceMonitor`` — Theorem-1 / fig-3 derived gauges +
                                 watchdog warnings.
+
+Every ``span``, with or without a bundle, is also a
+``jax.profiler.TraceAnnotation`` of the span's name: under a running
+``jax.profiler`` trace the engine's host phases land on the same clock
+as the device's ops (the compiled program names its own phases with
+``jax.named_scope``; see docs/observability.md).  With no profiler
+running an annotation costs a check of one flag.
 
 The bundle is BITWISE INERT on training numerics: it only reads floats
 the engine already computed, and the engine's compiled programs are
@@ -17,7 +24,10 @@ a full bundle attached — asserted in ``tests/test_obs.py``.
 """
 from __future__ import annotations
 
+import contextlib
 from typing import Any, Dict, Iterable, Optional, Sequence
+
+from jax.profiler import TraceAnnotation
 
 from .metrics import (SCHEMA_VERSION, ConsoleSink, JSONLSink, MemorySink,
                       MetricEvent, MetricsHub, Sink, load_jsonl,
@@ -33,20 +43,17 @@ __all__ = [
 ]
 
 
-class _NullSpan:
-    """Context manager that does nothing — what ``OBS_OFF.span`` returns,
-    so instrumented code has ONE code path whether obs is on or off."""
-
-    __slots__ = ()
-
-    def __enter__(self):
-        return None
-
-    def __exit__(self, *exc):
-        return False
-
-
-_NULL_SPAN = _NullSpan()
+@contextlib.contextmanager
+def _span(name: str, tracer: Optional[Tracer], args: Dict[str, Any]):
+    """One host span: a profiler annotation always, and the tracer's own
+    record (yielded) when one is attached — ONE code path whether obs is
+    on or off."""
+    with TraceAnnotation(name):
+        if tracer is None:
+            yield None
+        else:
+            with tracer.span(name, **args) as sp:
+                yield sp
 
 
 class Observability:
@@ -70,9 +77,7 @@ class Observability:
         self.monitor: Optional[ConvergenceMonitor] = monitor
 
     def span(self, name: str, **args: Any):
-        if self.tracer is None:
-            return _NULL_SPAN
-        return self.tracer.span(name, **args)
+        return _span(name, self.tracer, args)
 
     def compile_event(self, cause: str, **args: Any) -> None:
         if self.tracer is not None:
@@ -112,8 +117,9 @@ class Observability:
 
 
 class _ObsOff:
-    """The null bundle: every hook is a no-op.  The engine's default, so
-    un-instrumented runs pay one attribute read and one ``if`` per hook."""
+    """The null bundle: every hook but ``span``'s profiler annotation is a
+    no-op.  The engine's default, so un-instrumented runs pay one
+    attribute read and one ``if`` per hook."""
 
     enabled = False
     hub = None
@@ -123,7 +129,7 @@ class _ObsOff:
     __slots__ = ()
 
     def span(self, name: str, **args: Any):
-        return _NULL_SPAN
+        return _span(name, None, args)
 
     def compile_event(self, cause: str, **args: Any) -> None:
         pass

@@ -1,14 +1,13 @@
-"""Host-side span tracing for the DFL engine.
+"""Host-side span recording for the DFL engine.
 
 ``Tracer`` is a monotonic-clock (``time.perf_counter_ns``) span recorder
-for the HOST loop: compiled regions are timed as one opaque span bounded
-by an explicit ``jax.block_until_ready`` sync placed by the caller
-strictly OUTSIDE the jitted program (the engine only syncs when a tracer
-is attached, so tracing never changes dispatch behaviour of an untraced
-run — and never changes numerics of any run).  Spans nest through the
-``span()`` context manager; phases measured indirectly (the engine's
-consensus-replay attribution of local vs gossip time inside one compiled
-epoch step) are inserted with explicit timestamps via ``add_span``.
+for the HOST loop, the ``--chrome-trace`` view: it records the engine's
+``obs.span`` phases (``repro.obs``), which are also ``jax.profiler``
+annotations.  It adds no sync point, so ``dispatch`` measures enqueueing
+the compiled epoch program and ``readback`` the host's wait for it.  What
+the device does inside the program is in a ``jax.profiler`` trace, under
+the program's ``jax.named_scope`` phases, on the profiler's clock — not
+here.  Spans nest through the ``span()`` context manager.
 
 Besides spans the tracer records INSTANT events — most importantly
 ``compile`` events emitted by the engine whenever its per-M jit cache
@@ -78,11 +77,6 @@ class Tracer:
         self.instants: List[Dict[str, Any]] = []
         self._stack: List[Span] = []
 
-    def now(self) -> int:
-        """The tracer's clock, for callers timing external work (e.g. the
-        engine's consensus-replay probe) that lands via ``add_span``."""
-        return self._clock()
-
     @contextlib.contextmanager
     def span(self, name: str, **args: Any):
         sp = Span(name=name, t0_ns=self._clock(), depth=len(self._stack),
@@ -94,20 +88,6 @@ class Tracer:
             self._stack.pop()
             sp.t1_ns = self._clock()
             self.spans.append(sp)
-
-    def add_span(self, name: str, t0_ns: int, t1_ns: int,
-                 parent: Optional[Span] = None, **args: Any) -> Span:
-        """Record a span with EXPLICIT timestamps — for phases whose wall
-        time was measured out-of-band (the engine's local/gossip split of
-        one compiled step) and must be placed inside an already-closed
-        parent's interval."""
-        if t1_ns < t0_ns:
-            raise ValueError(f"span {name!r} ends before it starts")
-        depth = parent.depth + 1 if parent is not None else len(self._stack)
-        sp = Span(name=name, t0_ns=t0_ns, t1_ns=t1_ns, depth=depth,
-                  parent=parent, args=args)
-        self.spans.append(sp)
-        return sp
 
     def instant(self, name: str, **args: Any) -> None:
         self.instants.append({"name": name, "ts_ns": self._clock(),

@@ -22,6 +22,10 @@ from repro.obs import (OBS_OFF, SCHEMA_VERSION, ConsoleSink,
                        load_jsonl, validate_chrome_trace, validate_jsonl)
 from repro.optim import sgd
 
+# the engine's host phases, in the order run_epoch opens them
+ENGINE_SPANS = ("epoch", "fault-surgery", "schedule", "batch", "dispatch",
+                "readback", "host-aggregation")
+
 # ---------------------------------------------------------------------------
 # tracer: spans, nesting, Chrome export
 # ---------------------------------------------------------------------------
@@ -40,13 +44,13 @@ def _fake_clock():
 def test_span_nesting_and_ordering_invariants():
     tr = Tracer(clock=_fake_clock())
     with tr.span("epoch", epoch=0) as outer:
-        with tr.span("local-period"):
+        with tr.span("dispatch"):
             pass
-        with tr.span("gossip-period"):
+        with tr.span("readback"):
             pass
     # children appended at EXIT, before the outer span closes
     names = [s.name for s in tr.spans]
-    assert names == ["local-period", "gossip-period", "epoch"]
+    assert names == ["dispatch", "readback", "epoch"]
     local, gossip, epoch = tr.spans
     assert epoch is outer
     # time containment + sibling ordering under the monotonic clock
@@ -57,17 +61,6 @@ def test_span_nesting_and_ordering_invariants():
     assert epoch.depth == 0 and local.depth == 1 and gossip.depth == 1
     assert local.parent is epoch and gossip.parent is epoch
     assert epoch.args == {"epoch": 0}
-
-
-def test_add_span_places_explicit_intervals():
-    tr = Tracer(clock=_fake_clock())
-    with tr.span("epoch") as ep:
-        pass
-    sp = tr.add_span("gossip-period", ep.t0_ns, ep.t1_ns, parent=ep,
-                     method="consensus-replay")
-    assert ep.encloses(sp) and sp.depth == ep.depth + 1
-    with pytest.raises(ValueError):
-        tr.add_span("bad", 100, 50)
 
 
 def test_chrome_trace_export_is_valid_and_complete():
@@ -286,8 +279,8 @@ def _small_engine(obs=None, faults=None):
 
 def test_engine_history_bitwise_identical_with_obs_on():
     """The load-bearing contract: attaching the FULL obs stack (hub +
-    sinks + tracer with its block_until_ready sync points + monitor) must
-    not change a single bit of any training metric."""
+    sinks + tracer + monitor) must not change a single bit of any
+    training metric."""
     faults = FaultSchedule((FaultEvent(2, "drop", 1),
                             FaultEvent(4, "rejoin", 1)))
     epochs = 6
@@ -321,13 +314,14 @@ def test_engine_emits_spans_and_compile_events():
     for e in range(4):
         state, _ = eng.run_epoch(state, e, batch_fn)
     names = {s.name for s in tracer.spans}
-    assert {"epoch", "local-period", "gossip-period", "fault-surgery",
-            "host-aggregation"} <= names
+    assert names == set(ENGINE_SPANS)
     epochs = [s for s in tracer.spans if s.name == "epoch"]
     assert len(epochs) == 4
     for ep in epochs:
         kids = [s for s in tracer.spans if s.parent is ep]
-        assert kids and all(ep.encloses(k) for k in kids)
+        # every phase once per epoch, in the order the host runs them
+        assert [k.name for k in kids] == list(ENGINE_SPANS[1:])
+        assert all(ep.encloses(k) for k in kids)
     causes = [ev["args"]["cause"] for ev in tracer.instants
               if ev["name"] == "compile"]
     # M=3 cold trace, then the fault surgery re-jits at M=2
@@ -336,3 +330,59 @@ def test_engine_emits_spans_and_compile_events():
     validate_chrome_trace(tracer.to_chrome())
     # the hub-side history matches what the engine returned per epoch
     assert len(mem.history()["loss"]) == 4
+
+
+def test_superepoch_emits_the_same_phases_once_per_block():
+    tracer = Tracer()
+    topo = FLTopology(num_servers=3, clients_per_server=2, t_client=2,
+                      t_server=3, graph_kind="ring")
+    task = make_regression_task(topo, RegressionSpec(), seed=0)
+    eng = make_engine(topo, task["loss_fn"], sgd(1e-3), superepoch=2,
+                      obs=Observability(tracer=tracer))
+    state = init_dfl_state(eng.cfg, jnp.zeros((2,)), sgd(1e-3),
+                           jax.random.key(0))
+    eng.run(state, 4, task["batch_fn"])
+    blocks = [s for s in tracer.spans if s.name == "superepoch"]
+    assert len(blocks) == 2
+    for blk in blocks:
+        assert [k.name for k in tracer.spans if k.parent is blk] \
+            == list(ENGINE_SPANS[1:])
+    causes = [ev["args"]["cause"] for ev in tracer.instants
+              if ev["name"] == "compile"]
+    assert causes == ["first_trace"]
+
+
+def _profiled_spans(obs, epochs=3):
+    """Host annotations of ``epochs`` engine epochs under jax.profiler."""
+    import glob
+    import tempfile
+
+    from jax.profiler import ProfileData
+
+    eng, state, batch_fn = _small_engine(obs=obs)
+    state, _ = eng.run_epoch(state, 0, batch_fn)      # compile outside
+    tdir = tempfile.mkdtemp()
+    jax.profiler.start_trace(tdir)
+    for e in range(1, 1 + epochs):
+        state, _ = eng.run_epoch(state, e, batch_fn)
+    jax.profiler.stop_trace()
+    path = glob.glob(f"{tdir}/**/*.xplane.pb", recursive=True)[0]
+    return [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns)
+            for plane in ProfileData.from_file(path).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for ev in line.events
+            if ev.name in ENGINE_SPANS]
+
+
+@pytest.mark.parametrize("obs", ["off", "tracer"])
+def test_engine_spans_are_profiler_annotations(obs):
+    """Every engine phase is a jax.profiler annotation, once per epoch
+    and inside its epoch, whether a tracer is attached or not."""
+    bundle = None if obs == "off" else Observability(tracer=Tracer())
+    spans = _profiled_spans(bundle)
+    epochs = [(s, e) for n, s, e in spans if n == "epoch"]
+    assert len(epochs) == 3
+    for s0, e0 in epochs:
+        inner = sorted((s, n) for n, s, e in spans
+                       if n != "epoch" and s0 <= s and e <= e0)
+        assert [n for _, n in inner] == list(ENGINE_SPANS[1:])
