@@ -24,8 +24,10 @@ runs the int8 physical wire with error feedback, once through the shard_map
 collectives and once through the in-graph reference
 (``consensus.gossip_scan_wire_bucketed``), with the same seed and placement.
 The model keeps smollm-360m's widths at ``FOUR_CHIP_LAYERS`` of its 32
-layers: at full depth the wire's gathered codes and their f32 decode need
-16.4 GB per chip at 16 layers and 25.2 GB at 32.  Checks: the two wires'
+layers, the depth of the benchmark's four-chip cell.  (The shard_map step
+used to decode the gathered codes to f32, 16.4 GB per chip at 16 layers;
+since it mixes them in their chunk view its compile peaks at 7.3 GB per
+chip at 16 layers and 12.6 GB at 32.)  Checks: the two wires'
 final server weights agree (``PARAM_ATOL``), their losses are finite, every
 chip holds its server's share, each placed step compiled once, and each
 chip's bytes in use are within 25% of the mean.

@@ -351,8 +351,12 @@ class StochasticQuantizer(Compressor):
         pad = nc * self.chunk - length
         if pad:
             x32 = jnp.pad(x32, [(0, 0)] * (x32.ndim - 1) + [(0, pad)])
-        chunked = x32.reshape(x32.shape[:-1] + (nc, self.chunk))
-        absmax = jnp.max(jnp.abs(chunked), axis=-1)
+        return self._chunk_scales(
+            x32.reshape(x32.shape[:-1] + (nc, self.chunk)))
+
+    def _chunk_scales(self, x3: jax.Array) -> jax.Array:
+        """(..., nc) scales of f32 chunks laid out as (..., nc, chunk)."""
+        absmax = jnp.max(jnp.abs(x3), axis=-1)
         # multiply by the reciprocal CONSTANT, never divide: XLA's
         # simplifier rewrites float division by a constant into a
         # reciprocal multiply in SOME programs and not in others, which
@@ -372,18 +376,9 @@ class StochasticQuantizer(Compressor):
     def compress(self, x, key=None, *, dither=None):
         d = x.shape[-1]
         x32 = x.astype(jnp.float32)
-        scale = self._scales(x32)
         if dither is None:
             dither = (jax.random.uniform(key, x32.shape)
                       if key is not None else 0.5)
-        # Quantize by MULTIPLYING with the reciprocal of the on-wire scale
-        # (``inv`` is per-chunk, so the two tiny divisions are amortised
-        # over ``chunk`` elements): per-element division was the single
-        # hottest op of the physical-wire round on a host backend.  The
-        # reciprocal is computed from the canonical wire scale — every
-        # encoder (in-graph, shard_map, Pallas kernels) derives the same
-        # ``1/s_c`` bitwise, which is what keeps their codes identical.
-        inv = 1.0 / scale
         if d % self.chunk == 0:
             # chunk-multiple fast path (every bucketed-wire block, by
             # ``bucket_block`` construction): scale in the (..., nc,
@@ -394,13 +389,33 @@ class StochasticQuantizer(Compressor):
             x3 = x32.reshape(x32.shape[:-1] + (-1, self.chunk))
             u3 = (dither if jnp.ndim(dither) == 0
                   else jnp.reshape(dither, x3.shape))
-            q = (jnp.clip(jnp.floor(x3 * inv[..., None] + u3),
-                          -self.qmax, self.qmax)
-                 .astype(jnp.int8).reshape(x32.shape))
-        else:
-            q = jnp.clip(jnp.floor(x32 * self._per_elem(inv, d) + dither),
-                         -self.qmax, self.qmax).astype(jnp.int8)
+            q, scale = self.encode_chunks(x3, u3)
+            return Compressed(data=q.reshape(x32.shape), scale=scale)
+        scale = self._scales(x32)
+        q = jnp.clip(jnp.floor(x32 * self._per_elem(1.0 / scale, d)
+                               + dither),
+                     -self.qmax, self.qmax).astype(jnp.int8)
         return Compressed(data=q, scale=scale)
+
+    def encode_chunks(self, x3: jax.Array, dither) -> Tuple[jax.Array,
+                                                            jax.Array]:
+        """Quantize f32 chunks laid out as ``(..., nc, chunk)``: UNPACKED
+        int8 codes of the same shape and ``(..., nc)`` scales.  The chunk
+        view is the bucketed shard_map wire's native layout, so its encode
+        never reshapes; ``compress`` calls this on its chunk-multiple path,
+        which keeps the two one numerics definition.
+
+        Quantize by MULTIPLYING with the reciprocal of the on-wire scale
+        (per chunk, so the two tiny divisions are amortised over ``chunk``
+        elements): per-element division was the single hottest op of the
+        physical-wire round on a host backend.  The reciprocal is computed
+        from the canonical wire scale — every encoder (in-graph, shard_map,
+        Pallas kernels) derives the same ``1/s_c`` bitwise, which is what
+        keeps their codes identical."""
+        scale = self._chunk_scales(x3)
+        q = jnp.clip(jnp.floor(x3 * (1.0 / scale)[..., None] + dither),
+                     -self.qmax, self.qmax).astype(jnp.int8)
+        return q, scale
 
     def decompress(self, comp, d):
         scale = self._per_elem(comp.scale, d)

@@ -555,6 +555,35 @@ def gossip_scan_wire_bucketed(a: jax.Array, tree: Any, t_server: int,
         return _bucket_split(out, leaves, treedef)
 
 
+def wire_decode_mix(ref: jax.Array, acc: jax.Array, codes: jax.Array,
+                    scales: jax.Array, g_codes: jax.Array,
+                    g_scales: jax.Array, row: jax.Array
+                    ) -> Tuple[jax.Array, jax.Array]:
+    """Decode and mix one round of the bucketed wire on one device, in the
+    bucket's chunk view: ``ref``/``acc`` ``(nc, chunk)`` f32, this device's
+    own UNPACKED int8 ``codes`` ``(nc, chunk)`` and ``scales`` ``(nc,)``,
+    every server's ``g_codes`` ``(M, nc, chunk)`` int8 and ``g_scales``
+    ``(M, nc)``, and this device's mixing ``row`` ``(M,)``.  Returns
+    ``(ref', acc')``::
+
+        ref' = ref + code * scale                       (own row, local)
+        acc' = acc + sum_j (row[j] * g_scales[j]) * g_codes[j]
+
+    Each chunk's scale and mixing weight fold into ONE factor per chunk
+    that broadcasts along the chunk, and the codes convert to f32 inside
+    the same elementwise pass, so no f32 copy of the gathered codes and
+    no per-element scale array is ever materialised.  The sum runs one
+    server at a time, left to right, with all M terms (the row is traced:
+    a zero weight still adds its term) — the products and order of
+    ``gossip_scan_wire_bucketed``, which keeps the physical program
+    bit-identical to it."""
+    ref = ref + codes.astype(jnp.float32) * scales[:, None]
+    ws = row[:, None] * g_scales                        # (M, nc) folded
+    for j in range(g_codes.shape[0]):
+        acc = acc + ws[j][:, None] * g_codes[j].astype(jnp.float32)
+    return ref, acc
+
+
 def bucketed_roundtrip_tree(codec, tree: Any,
                             key: Optional[jax.Array] = None, *,
                             block: int = DEFAULT_GOSSIP_BLOCK,
@@ -956,34 +985,47 @@ def make_gossip_shard_map(mesh, t_server: int, leaf_specs: Any, *,
             # (iterate, own reference row, mixed-reference accumulator)
             # instead of the per-leaf form's (M, blk) reference matrix;
             # see ``gossip_scan_wire_bucketed`` for the telescoped
-            # recursion and why acc_t == (A · R_t)_i exactly.
+            # recursion and why acc_t == (A · R_t)_i exactly.  The loop
+            # carries the bucket in its chunk view ``(nc, chunk)``: encode,
+            # gather, decode and mix all work per chunk, so on a tiled
+            # device nothing is relaid out inside the loop — the leaves are
+            # packed into that view once and split out of it once.
+            chunk = codec.chunk
             with jax.named_scope("wire_pack"):
                 flat = jnp.concatenate(
                     [to_wire(leaf.astype(dtype)).reshape(-1)
                      for leaf in leaves])
                 d_tot = flat.size
-                blk, nb = _compressors.bucket_block(d_tot, block,
-                                                    codec.chunk)
+                blk, nb = _compressors.bucket_block(d_tot, block, chunk)
                 d_pad = nb * blk
                 if d_pad != d_tot:
                     flat = jnp.pad(flat, (0, d_pad - d_tot))
+                nc = d_pad // chunk
+                rows = flat.reshape(nc, chunk)
 
             def encode_round(t, delta):
                 """Round-``t`` bucket encode under the shared dither
                 convention — ONE definition used by both the loop body and
                 the out-of-loop ``shipped`` pre-pass, so the pre-pass is
-                elementwise-identical to what round 0 puts on the wire."""
+                elementwise-identical to what round 0 puts on the wire.
+                The dither is the flat bucket's, row-major in the chunk
+                view.  Returns UNPACKED (nc, chunk) int8 codes and (nc,)
+                scales."""
                 if key is not None:
                     dither = _compressors.wire_dither(
-                        key, (d_pad,), leaf=0, rnd=t, server=wire_server,
-                        block=0)
+                        key, (nc, chunk), leaf=0, rnd=t,
+                        server=wire_server, block=0)
                 else:
                     dither = 0.5
-                return codec.encode_block(delta, dither)
+                return codec.encode_chunks(delta, dither)
 
             def gather(codes, scales):
-                """Every server's round codes and chunk scales."""
+                """Every server's round codes and chunk scales: int8 codes
+                cross as they are, int4 codes packed two to a byte over
+                the flat bucket (``pack_int4``)."""
                 with jax.named_scope("wire_gather"):
+                    if codec.bits == 4:
+                        codes = _compressors.pack_int4(codes.reshape(-1))
                     if gather_codes:
                         g_codes = jax.lax.all_gather(codes, axis_name)
                     else:
@@ -1002,36 +1044,35 @@ def make_gossip_shard_map(mesh, t_server: int, leaf_specs: Any, *,
                             axis_name).astype(codes.dtype)
                     return g_codes, jax.lax.all_gather(scales, axis_name)
 
+            def code_rows(g_codes):
+                """Gathered wire codes as (M, nc, chunk) int8."""
+                if codec.bits == 4:
+                    g_codes = _compressors.unpack_int4(g_codes, d_pad)
+                return g_codes.reshape(m, nc, chunk)
+
             def round_fn_wire(t, carry):
                 """One bucketed quantized-wire round, delta-coded: encode
                 the innovation of my bucket against the receivers' shared
                 decoded reference of me, gather CODES (not floats), fold
-                every row's decoded delta into my own reference row and
-                the mixed-reference accumulator.  The delta's absmax
-                contracts with consensus, so per-hop quantization noise
-                vanishes instead of flooring (see ``gossip_scan_wire``)."""
-                w, ref, acc = carry            # (d_pad,) each
+                my own decoded delta into my reference row and every
+                row's into the mixed-reference accumulator.  The delta's
+                absmax contracts with consensus, so per-hop quantization
+                noise vanishes instead of flooring (see
+                ``gossip_scan_wire``)."""
+                # iterate and accumulator stay two carries even for an f32
+                # model, where they hold the same values from round 1 on:
+                # starting the accumulator as ``where(t > 0, w, 0)`` from
+                # one carry let the v5e compiler (libtpu 0.0.34) drop the
+                # select, so round 0 mixed onto the iterate
+                w, ref, acc = carry            # (nc, chunk) each
                 with jax.named_scope("wire_encode"):
                     delta = from_wire(w).astype(jnp.float32) - ref
                     codes, scales = encode_round(t, delta)
                 g_codes, g_scales = gather(codes, scales)
-                # Fused dequantize-and-mix: fold the per-chunk scales and
-                # the mixing-row weight into ONE broadcast factor per
-                # chunk, so the round never materialises the (M, d_pad)
-                # dequantized matrix or a per-element scale vector — on a
-                # memory-bound host this halves the decode-side passes.
-                # Term order stays one server at a time, left to right,
-                # matching ``gossip_scan_wire_bucketed`` product for
-                # product (the oracle folds identically).
                 with jax.named_scope("wire_decode_mix"):
-                    c3 = codec.code_chunks(g_codes, d_pad)  # (M, nc, chunk)
-                    ref = ref + (c3[idx] * g_scales[idx][:, None]
-                                 ).reshape(d_pad)
-                    ws = row[:, None] * g_scales            # (M, nc) folded
-                    acc3 = acc.reshape(-1, codec.chunk)
-                    for j in range(m):
-                        acc3 = acc3 + ws[j][:, None] * c3[j]
-                    acc = acc3.reshape(d_pad)
+                    ref, acc = wire_decode_mix(
+                        ref, acc, codes, scales, code_rows(g_codes),
+                        g_scales, row)
                     return to_wire(acc.astype(dtype)), ref, acc
 
             def round_fn_wire_stale(t, carry):
@@ -1053,15 +1094,9 @@ def make_gossip_shard_map(mesh, t_server: int, leaf_specs: Any, *,
                     codes, scales = encode_round(t, delta)
                 g_codes, g_scales = gather(codes, scales)
                 with jax.named_scope("wire_decode_mix"):
-                    own3 = codec.code_chunks(codes, d_pad)  # (nc, chunk)
-                    ref = ref + (own3 * scales[:, None]).reshape(d_pad)
-                    old_c, old_s = rc[0], rs[0]
-                    c3 = codec.code_chunks(old_c, d_pad)    # (M, nc, chunk)
-                    ws = row[:, None] * old_s               # (M, nc) folded
-                    acc3 = acc.reshape(-1, codec.chunk)
-                    for j in range(m):
-                        acc3 = acc3 + ws[j][:, None] * c3[j]
-                    acc = acc3.reshape(d_pad)
+                    ref, acc = wire_decode_mix(
+                        ref, acc, codes, scales, code_rows(rc[0]), rs[0],
+                        row)
                     w = jnp.where(t >= staleness,
                                   to_wire(acc.astype(dtype)), w)
                 with jax.named_scope("wire_gather"):
@@ -1069,7 +1104,7 @@ def make_gossip_shard_map(mesh, t_server: int, leaf_specs: Any, *,
                     rs = jnp.concatenate([rs[1:], g_scales[None]], axis=0)
                 return w, ref, acc, rc, rs
 
-            zeros = jnp.zeros((d_pad,), jnp.float32)
+            zeros = jnp.zeros((nc, chunk), jnp.float32)
             if with_shipped:
                 # what this device shipped of its own model (the EF hook)
                 # is its round-0 decoded transmission: ref_1 = dec_0[own].
@@ -1084,29 +1119,27 @@ def make_gossip_shard_map(mesh, t_server: int, leaf_specs: Any, *,
                 # bucket-sized selects.
                 with jax.named_scope("wire_encode"):
                     codes0, scales0 = encode_round(
-                        0, from_wire(flat).astype(jnp.float32) - zeros)
-                    shipped = codec.decode_block(codes0, scales0, d_pad)
+                        0, from_wire(rows).astype(jnp.float32) - zeros)
+                    shipped = codes0.astype(jnp.float32) * scales0[:, None]
             else:
                 shipped = zeros
             if staleness == 0:
                 w, _, _ = jax.lax.fori_loop(
-                    0, t_server, round_fn_wire, (flat, zeros, zeros))
+                    0, t_server, round_fn_wire, (rows, zeros, zeros))
             else:
                 # in-flight ring pre-filled with zero codes + unit scales
                 # (decode to nothing), so consumption is unconditional and
                 # inert before round ``staleness``
-                code_abs = jax.eval_shape(
-                    lambda x: codec.encode_block(x, 0.5)[0],
-                    jax.ShapeDtypeStruct((d_pad,), jnp.float32))
-                ring_c = jnp.zeros((staleness, m) + code_abs.shape,
-                                   code_abs.dtype)
-                ring_s = jnp.ones(
-                    (staleness, m, d_pad // codec.chunk), jnp.float32)
+                code_shape = ((m, d_pad // 2) if codec.bits == 4
+                              else (m, nc, chunk))
+                ring_c = jnp.zeros((staleness,) + code_shape, jnp.int8)
+                ring_s = jnp.ones((staleness, m, nc), jnp.float32)
                 w, _, _, _, _ = jax.lax.fori_loop(
                     0, t_server, round_fn_wire_stale,
-                    (flat, zeros, zeros, ring_c, ring_s))
+                    (rows, zeros, zeros, ring_c, ring_s))
             with jax.named_scope("wire_pack"):
-                out = from_wire(w)
+                out = from_wire(w).reshape(-1)
+                shipped = shipped.reshape(-1)
                 new_leaves, shipped_leaves, off = [], [], 0
                 for leaf in leaves:
                     size = leaf.size

@@ -17,6 +17,7 @@ import dataclasses
 import importlib
 import importlib.util
 import pathlib
+import re
 
 import jax
 import jax.numpy as jnp
@@ -25,6 +26,7 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
+from repro.comm.compressors import bucket_block
 from repro.core import init_dfl_state, make_engine
 from repro.core.schedule import EpochSchedule
 from repro.launch import sharding as shd
@@ -114,19 +116,27 @@ def test_full_width_epoch_step_fits_one_chip(one_chip):
     assert 0 < peak < HBM_BYTES, peak
 
 
-@pytest.mark.parametrize("backend", ["shard_map", "einsum"])
-def test_four_chip_placed_step_fits_each_chip(topo, backend):
-    """M=4 servers, one per chip of the 2x2 mesh, int8 physical wire with
-    error feedback: the four-chip smoke phase's epoch step, at its depth.
-    Every chip's share must fit its HBM."""
+def _placed_four_chip_step(topo, backend):
+    """Compile chip_smoke's four-chip phase's epoch step: M=4 servers, one
+    per chip of the 2x2 mesh, int8 physical wire with error feedback, at
+    its depth.  Returns the compiled step and, for the shard_map wire,
+    the element count of one round's gathered codes (M x the padded
+    bucket)."""
     mesh = Mesh(np.array(topo.devices).reshape(4), ("server",))
     wire = dict(SMOKE.WIRE)
+    gathered = None
     if backend == "shard_map":
         def build(topo_fl, params):
+            nonlocal gathered
             server = jax.eval_shape(lambda p: jax.tree.map(
                 lambda x: jnp.zeros((4,) + x.shape, x.dtype), p), params)
-            return shd.fl_consensus_backend(topo_fl, mesh, server,
-                                            tp_axis=None, **wire)
+            be = shd.fl_consensus_backend(topo_fl, mesh, server,
+                                          tp_axis=None, **wire)
+            blk, nb = bucket_block(
+                sum(x.size for x in jax.tree.leaves(params)),
+                be.inner.block, be.compressor.chunk)
+            gathered = 4 * blk * nb
+            return be
         kw = {"backend_fn": build}
     else:
         kw = wire
@@ -142,8 +152,50 @@ def test_four_chip_placed_step_fits_each_chip(topo, backend):
         _shaped(state, state_sh),
         _shaped(batch, jax.tree.map(lambda _: batch_sh, batch)),
         _shaped(sched, jax.tree.map(lambda _: rep, sched))).compile()
+    return compiled, gathered
+
+
+@pytest.fixture(scope="module")
+def placed_step(topo):
+    """``_placed_four_chip_step`` by backend, compiled once per module."""
+    cache = {}
+
+    def get(backend):
+        if backend not in cache:
+            cache[backend] = _placed_four_chip_step(topo, backend)
+        return cache[backend]
+    return get
+
+
+@pytest.mark.parametrize("backend", ["shard_map", "einsum"])
+def test_four_chip_placed_step_fits_each_chip(placed_step, backend):
+    """M=4 servers, one per chip of the 2x2 mesh, int8 physical wire with
+    error feedback: the four-chip smoke phase's epoch step, at its depth.
+    Every chip's share must fit its HBM."""
+    compiled, _ = placed_step(backend)
     peak = compiled.memory_analysis().peak_memory_in_bytes
     assert 0 < peak < HBM_BYTES, peak
+
+
+# The same shard_map step's per-chip peak before the wire decoded and
+# mixed in the bucket's chunk view (commit 6bc7583, JAX 0.9.0 with libtpu
+# 0.0.34, compiled as in ``_placed_four_chip_step``): 12,165,821,440
+# bytes, most of it f32 copies of the gathered codes.
+F32_DECODE_PEAK_BYTES = 12_165_821_440
+
+
+def test_four_chip_wire_never_decodes_the_gather_to_f32(placed_step):
+    """The shard_map wire decodes and mixes the gathered int8 codes in one
+    elementwise pass: the compiled four-chip step holds no f32 buffer the
+    size of the gathered codes (M x the padded bucket), and each chip's
+    peak is at least 2 GB under the program that converted them."""
+    compiled, gathered = placed_step("shard_map")
+    hlo = compiled.as_text()
+    sizes = {int(np.prod([int(n) for n in dims.split(",")]))
+             for dims in re.findall(r"f32\[([\d,]+)\]", hlo)}
+    assert max(sizes) < gathered, (max(sizes), gathered)
+    peak = compiled.memory_analysis().peak_memory_in_bytes
+    assert peak <= F32_DECODE_PEAK_BYTES - 2e9, peak
 
 
 def _kernel(name):

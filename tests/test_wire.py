@@ -244,6 +244,116 @@ def test_bucketed_roundtrip_tree_matches_round0(rng_key):
                                    rtol=1e-6, atol=1e-6)
 
 
+def _chunk_round_inputs(m, nc, chunk, bits, seed=0):
+    """One round's encoded innovations of ``m`` servers over an
+    ``(nc, chunk)`` bucket view whose LAST chunk is all pad (zero delta:
+    zero codes, scale 1), encoded the way the flat wire encodes them, plus
+    the f32 reference band and accumulator the round starts from."""
+    codec = cp.StochasticQuantizer(bits=bits, chunk=chunk)
+    k = jax.random.split(jax.random.key(seed), 4)
+    delta = jax.random.normal(k[0], (m, nc * chunk)) * 3
+    delta = delta.at[:, (nc - 1) * chunk:].set(0.0)
+    dither = jax.random.uniform(k[1], (m, nc * chunk))
+    codes, scales = codec.encode_block(delta, dither)
+    ref = jax.random.normal(k[2], (nc * chunk,))
+    acc = jax.random.normal(k[3], (nc * chunk,))
+    return codec, delta, dither, codes, scales, ref, acc
+
+
+@pytest.mark.parametrize("bits", [8, 4])
+def test_encode_chunks_is_the_flat_encode(bits):
+    """The chunk-view encode of the shard_map wire gives the flat
+    ``encode_block``'s codes (unpacked) and scales bit for bit, the
+    all-pad chunk included: the codes a device mixes from its own encode
+    are the integers its row of the gather carries."""
+    m, nc, chunk = 3, 13, 16
+    codec, delta, dither, codes, scales, _, _ = _chunk_round_inputs(
+        m, nc, chunk, bits)
+    q3, s3 = jax.jit(codec.encode_chunks)(
+        delta.reshape(m, nc, chunk), dither.reshape(m, nc, chunk))
+    flat = (cp.unpack_int4(codes, nc * chunk) if bits == 4 else codes)
+    np.testing.assert_array_equal(np.asarray(q3.reshape(m, -1)),
+                                  np.asarray(flat))
+    np.testing.assert_array_equal(np.asarray(s3), np.asarray(scales))
+    np.testing.assert_array_equal(np.asarray(s3[:, -1]), 1.0)
+    np.testing.assert_array_equal(np.asarray(q3[:, -1]), 0)
+
+
+@pytest.mark.parametrize("m", [2, 4])
+@pytest.mark.parametrize("nc", [5, 13], ids=["nc5", "nc13_ragged8"])
+def test_wire_decode_mix_is_the_flat_round_bitwise(m, nc):
+    """``wire_decode_mix`` in the chunk view is bitwise the flat round
+    formula it replaced: the own reference row updated from the device's
+    LOCAL codes equals the update from its row of the gathered codes, and
+    the folded mix (one ``row[j] * scale`` factor per chunk, servers
+    summed left to right) equals the mix over the f32-decoded gather —
+    for every device of a Metropolis ring (at M=4 each row has a zero
+    weight, whose term is still added), an all-pad last chunk (scale 1),
+    and chunk counts not a multiple of the 8-row tile."""
+    chunk = 16
+    d_pad = nc * chunk
+    codec, _, _, codes, scales, ref, acc = _chunk_round_inputs(
+        m, nc, chunk, 8, seed=m + nc)
+    a = _ring(m)
+    if m == 4:
+        assert float(a[0, 2]) == 0.0
+
+    @jax.jit
+    def flat_round(ref, acc, g_codes, g_scales, row, idx):
+        c3 = codec.code_chunks(g_codes, d_pad)             # (M, nc, chunk)
+        ref = ref + (c3[idx] * g_scales[idx][:, None]).reshape(d_pad)
+        ws = row[:, None] * g_scales
+        acc3 = acc.reshape(-1, chunk)
+        for j in range(m):
+            acc3 = acc3 + ws[j][:, None] * c3[j]
+        return ref, acc3.reshape(d_pad)
+
+    @jax.jit
+    def chunk_round(ref, acc, g_codes, g_scales, row, idx):
+        return cns.wire_decode_mix(
+            ref.reshape(nc, chunk), acc.reshape(nc, chunk),
+            g_codes[idx].reshape(nc, chunk), g_scales[idx],
+            g_codes.reshape(m, nc, chunk), g_scales, row)
+
+    for idx in range(m):
+        want = flat_round(ref, acc, codes, scales, a[idx], idx)
+        got = chunk_round(ref, acc, codes, scales, a[idx], idx)
+        for name, g, w in zip(("ref", "acc"), got, want):
+            np.testing.assert_array_equal(
+                np.asarray(g).reshape(-1), np.asarray(w),
+                err_msg=f"m={m} nc={nc} device={idx} {name}")
+
+
+@pytest.mark.parametrize("staleness", [0, 1])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_shard_map_wire_one_device_matches_bucketed_reference(dtype,
+                                                              staleness):
+    """The shard_map wire program on a one-device mesh is bitwise the
+    in-graph bucketed wire, for an f32 model and a bf16 model (u16 bit
+    patterns on the wire), synchronous and stale: the loop starts the
+    accumulator from zero, not from the iterate, in round 0."""
+    from jax.sharding import PartitionSpec as P
+    mesh = jax.sharding.Mesh(np.array(jax.devices()[:1]), ("server",))
+    tree = {"w": (jax.random.normal(jax.random.key(0), (1, 4, 33)) * 2
+                  ).astype(dtype),
+            "b": jax.random.normal(jax.random.key(1), (1, 7)).astype(dtype)}
+    specs = {"w": P("server", None, None), "b": P("server", None)}
+    codec = cp.StochasticQuantizer(bits=8, chunk=16)
+    key = jax.random.key(9)
+    a = jnp.ones((1, 1), jnp.float32)
+    run = cns.make_gossip_shard_map(mesh, 5, specs, block=32, codec=codec,
+                                    staleness=staleness)
+    want = jax.jit(lambda t: cns.gossip_scan_wire_bucketed(
+        a, t, 5, codec, key, block=32, staleness=staleness))(tree)
+    got = jax.jit(run)(a, tree, key)
+    for k in tree:
+        assert got[k].dtype == dtype
+        np.testing.assert_array_equal(
+            np.asarray(got[k].astype(jnp.float32)),
+            np.asarray(want[k].astype(jnp.float32)), err_msg=k)
+
+
 # ---------------------------------------------------------------------------
 # CompressedBackend wire='physical': dispatch, EF, push-sum, validation
 # ---------------------------------------------------------------------------
