@@ -1,12 +1,14 @@
-"""Model FLOPs of training, and the chips' published peaks.
+"""The FLOPs convention of training, and the chips' published peaks.
 
 Convention: forward plus backward is 6 x the parameters that enter a
-matrix multiplication (the tied embedding counts once, as the head; the
-embedding lookup, norms and elementwise work count zero), plus attention's
-score and value products at the full sequence length: 12 x layers x heads
-x head size x sequence per token (the causal mask is not halved: the
-trainer computes every score).  Recomputation counts zero, and so do
-aggregation and gossip.
+matrix multiplication for a token (the tied embedding counts once, as the
+head; the embedding lookup, norms and elementwise work count zero; of an
+expert layer only the experts a token is routed to), plus attention's
+score and value products at the full sequence length: 6 x sequence x the
+attention width, the sum over layers of heads x (query-key size + value
+size) (the causal mask is not halved: the trainer computes every score).
+Recomputation counts zero, and so do aggregation and gossip.  Each model
+plug-in (``models/``) counts its own parameters and width by this rule.
 """
 from __future__ import annotations
 
@@ -17,19 +19,9 @@ from typing import Any, Dict
 PEAKS = pathlib.Path(__file__).resolve().parent / "peaks.json"
 
 
-def matmul_params(cfg: Dict[str, Any]) -> int:
-    d, h = cfg["hidden_size"], cfg["num_attention_heads"]
-    kvh, ff = cfg["num_key_value_heads"], cfg["intermediate_size"]
-    hd = cfg.get("head_dim", d // h)
-    per_layer = d * hd * (2 * h + 2 * kvh) + 3 * d * ff
-    return cfg["num_hidden_layers"] * per_layer + cfg["vocab_size"] * d
-
-
-def train_flops_per_token(cfg: Dict[str, Any], seq_len: int) -> int:
-    d, h = cfg["hidden_size"], cfg["num_attention_heads"]
-    hd = cfg.get("head_dim", d // h)
-    attn = 12 * cfg["num_hidden_layers"] * h * hd * seq_len
-    return 6 * matmul_params(cfg) + attn
+def training(matmul_params: int, attention_width: int, seq_len: int) -> int:
+    """Model FLOPs of training one token at ``seq_len`` positions."""
+    return 6 * (matmul_params + seq_len * attention_width)
 
 
 def peaks(device_kind: str) -> Dict[str, Any]:

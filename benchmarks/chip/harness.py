@@ -3,7 +3,11 @@
 A cell (``BENCHMARK.json``'s ``workloads``) names a configuration file
 (model sizes and the federation's layout), a traffic file (the epoch
 schedule and the token distribution) and, under ``limits/``, the limits of
-the numbers that decide ``correct``.  Nothing here is specific to a cell.
+the numbers that decide ``correct``.  The configuration names its model
+plug-in (``models/``), which knows the model's block: the trainer's
+configuration for it, the seeded weights, the plain loss, the FLOPs and
+the model's own named scopes.  Nothing here is specific to a cell or a
+model.
 
 Set-up assembles the federation with the calls ``launch/train.py``'s
 ``train_dynamic`` makes (topology, loss, SGD, consensus backend,
@@ -17,6 +21,9 @@ on with the same engine and state: ``run_epoch`` in a loop for
 clock after it marks the end of that epoch on the chip.  After the window
 the peak of device memory is read, the trainer's state is freed, and the
 plain reference (``reference.py``) replays the first epochs for the check.
+A traced run (``--trace 1``) also compiles the epoch program afresh once
+the peak is read, for the named scopes on its ops and its memory, and
+hands both to the per-layer metrics' readers (``metrics/``).
 """
 from __future__ import annotations
 
@@ -32,11 +39,12 @@ import shutil
 import sys
 import tempfile
 import time
-from typing import Any, Callable, Dict, List, Optional
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental.compilation_cache import compilation_cache
 
 from repro.configs import get_arch
 from repro.core import FLTopology, init_dfl_state, make_engine
@@ -45,7 +53,7 @@ from repro.launch.train import resolve_consensus_backend, server_mesh
 from repro.models import transformer as tf
 from repro.optim import sgd
 
-from benchmarks.chip import flops, reference, trace_reduce
+from benchmarks.chip import flops, models, phases, reference, trace_reduce
 from benchmarks.chip.traffic import TokenStream
 
 CHIP = pathlib.Path(__file__).resolve().parent
@@ -56,14 +64,6 @@ TRACE_EPOCHS = 3
 # a leaf whose reference change after the first epoch is under this share
 # of the median leaf's moves by rounding alone, and is not compared
 STILL_LEAF = 1e-3
-# the configuration file's model keys, and the trainer's names for them
-ARCH_KEYS = {"hidden_size": "d_model", "intermediate_size": "d_ff",
-             "num_attention_heads": "num_heads",
-             "num_key_value_heads": "num_kv_heads",
-             "num_hidden_layers": "num_layers", "vocab_size": "vocab_size",
-             "rms_norm_eps": "norm_eps", "rope_theta": "rope_theta",
-             "tie_word_embeddings": "tie_embeddings",
-             "attention_bias": "use_bias"}
 COMPILE_EVENTS = ("/jax/core/compile/jaxpr_trace_duration",
                   "/jax/core/compile/backend_compile_duration",
                   "/jax/compilation_cache/cache_retrieval_time_sec")
@@ -78,6 +78,8 @@ class Cell:
     limits: Dict[str, float]
     end_to_end: List[Dict[str, Any]]
     per_layer: List[Dict[str, Any]]
+    # the benchmark's directory in the checkout the cell came from
+    home: pathlib.Path = CHIP
 
 
 def _applies(metric: Dict[str, Any], cell: str) -> bool:
@@ -85,6 +87,8 @@ def _applies(metric: Dict[str, Any], cell: str) -> bool:
 
 
 def load_cell(name: str, root: pathlib.Path = ROOT) -> Cell:
+    """The cell ``name`` of the checkout at ``root``."""
+    home = root / CHIP.relative_to(ROOT)
     spec = json.loads((root / "BENCHMARK.json").read_text())
     cells = {w["name"]: w for w in spec["workloads"]}
     if name not in cells:
@@ -95,34 +99,24 @@ def load_cell(name: str, root: pathlib.Path = ROOT) -> Cell:
         name=name, chips=wl["chips"],
         config=json.loads((root / cfg["file"]).read_text()),
         traffic=json.loads(
-            (CHIP / "traffic" / f"{wl['traffic']}.json").read_text()),
-        limits=json.loads((CHIP / "limits" / f"{name}.json").read_text()),
+            (home / "traffic" / f"{wl['traffic']}.json").read_text()),
+        limits=json.loads((home / "limits" / f"{name}.json").read_text()),
         end_to_end=[m for m in spec["end_to_end"] if _applies(m, name)],
-        per_layer=[m for m in spec["per_layer"] if _applies(m, name)])
-
-
-def arch_config(config: Dict[str, Any]):
-    """The trainer's ``ArchConfig`` with the configuration file's sizes."""
-    base = get_arch(config["program_arch"])
-    if (base.family != "dense" or base.moe or base.mla or base.mamba
-            or base.encdec or base.frontend or base.qk_norm
-            or tuple(base.layer_pattern) != ("global",)
-            or base.attn_logit_softcap or base.final_logit_softcap
-            or config["hidden_act"] != "silu"):
-        raise ValueError(f"{config['program_arch']} is not the plain "
-                         f"Llama-style block that reference.py computes")
-    kw = {dst: config[src] for src, dst in ARCH_KEYS.items()}
-    kw["head_dim"] = config["hidden_size"] // config["num_attention_heads"]
-    return dataclasses.replace(base, act="silu", **kw)
+        per_layer=[m for m in spec["per_layer"] if _applies(m, name)],
+        home=home)
 
 
 class Federation:
     """The trainer's federation for one configuration and traffic mix:
-    the engine, and fresh seeded states for it."""
+    the engine, and fresh seeded states for it.  ``models_dir``: the
+    directory of the model plug-ins."""
 
-    def __init__(self, config: Dict[str, Any], traffic: Dict[str, Any]):
+    def __init__(self, config: Dict[str, Any], traffic: Dict[str, Any],
+                 models_dir: pathlib.Path = models.HERE):
         self.config, self.traffic = config, traffic
-        self.arch = arch_config(config)
+        self.model = models.load(config, models_dir)
+        self.arch = self.model.arch_config(config,
+                                           get_arch(config["program_arch"]))
         self.topo = FLTopology(
             num_servers=config["servers"],
             clients_per_server=config["clients_per_server"],
@@ -130,9 +124,9 @@ class Federation:
             graph_kind=config["graph"], mixing="metropolis")
         self.optimizer = sgd(config["gamma"])
         loss_fn = tf.make_loss_fn(
-            self.arch, tf.ApplyOptions(remat=False, attn_impl="reference"))
+            self.arch, tf.ApplyOptions(**self.model.apply_options(config)))
         self._weights = jax.jit(
-            lambda key: reference.init_weights(key, config))
+            lambda key: self.model.init_weights(key, config))
         abstract = jax.eval_shape(self._weights, jax.random.key(0))
         want = jax.eval_shape(lambda k: tf.init_params(k, self.arch),
                               jax.random.key(0))
@@ -261,7 +255,8 @@ def run_reference(cell: Cell, w0, seed: int, dtype=jnp.float32
                   ) -> Dict[str, Any]:
     """The reference's first epochs from ``w0`` on the cell's tokens."""
     stream = token_stream(cell.config, cell.traffic, seed)
-    ref = reference.Reference(cell.config, cell.traffic, dtype=dtype)
+    model = models.load(cell.config, cell.home / "models")
+    ref = reference.Reference(cell.config, cell.traffic, model, dtype=dtype)
     return ref.run(w0, stream.epoch_tokens, seed, CHECK_STEPS, CAPTURE)
 
 
@@ -287,9 +282,9 @@ def compile_watch():
         jax.monitoring.unregister_event_duration_listener(listen)
 
 
-def _reader(name: str) -> Callable:
+def _reader(name: str, metrics_dir: pathlib.Path) -> Callable:
     spec = importlib.util.spec_from_file_location(
-        f"chipbench_metric_{name}", CHIP / "metrics" / f"{name}.py")
+        f"chipbench_metric_{name}", metrics_dir / f"{name}.py")
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod.read
@@ -352,6 +347,30 @@ def traced_epochs(fed: Federation, state, epoch0: int, batch_fn,
     return state, records, trace
 
 
+def program_afresh(fed: Federation, state, epoch: int, batch_fn
+                   ) -> Tuple[str, Dict[str, int]]:
+    """The epoch program that ``run_epoch(state, epoch, batch_fn)`` would
+    run, compiled anew: its HLO text and its memory analysis (argument,
+    output and temporary bytes a chip).  JAX's in-memory caches are
+    cleared and the persistent cache is off for the compile: that cache's
+    key leaves the ops' metadata out, so a program from it carries the
+    scope names of whichever compile filled it.  Nothing runs."""
+    was = jax.config.jax_enable_compilation_cache
+    jax.clear_caches()
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        program = fed.engine.epoch_program(state, epoch, batch_fn)
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+    mem = program.memory_analysis()
+    return program.as_text(), {
+        "argument_bytes": int(mem.argument_size_in_bytes),
+        "output_bytes": int(mem.output_size_in_bytes),
+        "temp_bytes": int(mem.temp_size_in_bytes)}
+
+
 def run(cell: Cell, seed: int, seconds: float, trace: bool,
         t_start: float, plant: Optional[Callable] = None,
         devices=None, keep_trace: Optional[str] = None) -> Dict[str, Any]:
@@ -359,7 +378,7 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
     process's start on the ``time.perf_counter`` clock; ``plant(fed)``
     breaks the trainer under the harness (the fault tests use it)."""
     devices = devices or jax.devices()[:cell.chips]
-    fed = Federation(cell.config, cell.traffic)
+    fed = Federation(cell.config, cell.traffic, cell.home / "models")
     batch_fn = batches(cell, seed)
     state = fed.new_state(seed)
     if plant is not None:
@@ -383,6 +402,11 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
     peak = max(int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
                for d in devices)
     failed = sum(not math.isfinite(r["loss"]) for r in records)
+    if trace:
+        hlo, memory = program_afresh(fed, state, CHECK_STEPS + len(records),
+                                     batch_fn)
+        print("epoch program: " + ", ".join(
+            f"{k} {v}" for k, v in memory.items()), file=sys.stderr)
     del state
     fed.engine = None
     gc.collect()
@@ -402,14 +426,15 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
               "count": len(devices), "memory_peak_bytes": peak}
     if trace:
         ctx = {"trace": red, "tokens_per_s": tokens_per_s,
-               "flops_per_token": flops.train_flops_per_token(
+               "flops_per_token": fed.model.train_flops_per_token(
                    cell.config, cell.config["seq_len"]),
                "peak": (flops.peaks(dev.device_kind)
                         if dev.platform == "tpu" else None),
-               "chips": len(devices), "records": records}
+               "chips": len(devices), "records": records, "memory": memory,
+               **phases.trace_context(tr, red, hlo, fed.model.SCOPES)}
         metrics = {}
         for m in cell.per_layer:
-            v = _reader(m["name"])(ctx)
+            v = _reader(m["name"], cell.home / "metrics")(ctx)
             if v is not None:
                 metrics[m["name"]] = {"value": v, "unit": m["unit"]}
         if red:

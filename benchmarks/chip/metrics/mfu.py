@@ -1,6 +1,6 @@
 """Model FLOPs of the window's client training over the chips' bf16 peak:
-FLOPs per token (``flops.train_flops_per_token``) x tokens/s / (chips x
-peak)."""
+FLOPs per token (the model plug-in's ``train_flops_per_token``) x
+tokens/s / (chips x peak)."""
 
 
 def read(ctx):

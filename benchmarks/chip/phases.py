@@ -3,21 +3,23 @@
 of the compiled epoch program, and the engine's host phases.
 
 The trainer names the phases of its compiled epoch program with
-``jax.named_scope`` (``SCOPES``) and opens a ``jax.profiler``
-annotation for each phase of its host loop (``ENGINE_SPANS``).  A device
-trace names an op by its HLO instruction only, so ``scope_map`` reads the
-scopes off the compiled program's HLO text (the ``op_name`` metadata of
-each instruction; a fusion carries its root's), from
-``DynamicFederationEngine.epoch_program(...).as_text()``.  Then, per
-chip, ``scope_ns`` gives each scope's device time inside the epoch
-program: the union of the intervals of the scope's ops on the ``XLA Ops``
-line and of its collectives in flight on the ``Async XLA Ops`` line,
-clipped to the traced window.  An op counts
-toward every scope on its path; the program's ops with no scope go under
+``jax.named_scope`` (``SCOPES``; a model plug-in names the further scopes
+of its block, ``models/<name>.py`` ``SCOPES``) and opens a
+``jax.profiler`` annotation for each phase of its host loop
+(``ENGINE_SPANS``).  A device trace names an op by its HLO instruction
+only, so ``scope_map`` reads the scopes off the compiled program's HLO
+text (the ``op_name`` metadata of each instruction; a fusion carries its
+root's), from ``DynamicFederationEngine.epoch_program(...).as_text()``.
+Then, per chip, ``scope_ns`` gives each scope's device time inside the
+epoch program: the union of the intervals of the scope's ops on the
+``XLA Ops`` line and of its collectives in flight on the ``Async XLA
+Ops`` line, clipped to the traced window.  An op counts toward every
+scope on its path; the program's ops with no scope go under
 ``unscoped``.  ``host_turnaround_ns`` is the host's time from one
 epoch's ``readback`` end to the next epoch's ``dispatch`` start, and
 ``idle_by_span`` puts the chip's idle time down to the innermost host
-span it fell under.
+span it fell under.  ``trace_context`` gives the benchmark's metric
+readers the scopes' times and the host's spans of a traced run.
 
 Run as a script it measures one cell of ``BENCHMARK.json`` on the chips
 of this machine: the harness's set-up and a window of ``--seconds``
@@ -45,7 +47,7 @@ import os  # noqa: E402
 import pathlib  # noqa: E402
 import re  # noqa: E402
 import sys  # noqa: E402
-from typing import Dict, List, Optional, Tuple  # noqa: E402
+from typing import Dict, List, Optional, Sequence, Tuple  # noqa: E402
 
 ROOT = pathlib.Path(__file__).resolve().parents[2]
 for _p in (str(ROOT / "src"), str(ROOT)):
@@ -63,9 +65,8 @@ SCOPES = ("local_period", "embed", "attention", "mlp", "lm_head",
 UNSCOPED = "unscoped"
 PROGRAM = "program"       # the busy time of all the program's ops
 # the engine's host spans (core/engine.py run_epoch), and the harness's
-ENGINE_SPANS = ("epoch", "fault-surgery", "schedule", "batch", "dispatch",
-                "readback", "host-aggregation")
-HOST_SPANS = trace_reduce.ANNOTATIONS + ENGINE_SPANS
+ENGINE_SPANS = trace_reduce.ENGINE_SPANS
+HOST_SPANS = trace_reduce.HOST_SPANS
 # per-epoch metric -> the scope it reads
 SCOPE_METRICS = {"local_ms": "local_period", "sgd_update_ms": "sgd_update",
                  "attention_ms": "attention", "mlp_ms": "mlp",
@@ -81,26 +82,29 @@ _COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s.*\{\s*$")
 _WRAPPED = re.compile(r"^[\w\-]+\((.*)\)$")
 
 
-def path_scopes(op_name: str) -> Tuple[str, ...]:
-    """The known scopes on an ``op_name`` metadata path, outermost first;
-    autodiff's wrappers (``transpose(jvp(attention))``) are stripped."""
+def path_scopes(op_name: str, scopes: Sequence[str] = SCOPES
+                ) -> Tuple[str, ...]:
+    """The scopes of ``scopes`` on an ``op_name`` metadata path, outermost
+    first; autodiff's wrappers (``transpose(jvp(attention))``) are
+    stripped."""
     out = []
     for seg in op_name.split("/"):
         m = _WRAPPED.match(seg)
         while m:
             seg = m.group(1)
             m = _WRAPPED.match(seg)
-        if seg in SCOPES and seg not in out:
+        if seg in scopes and seg not in out:
             out.append(seg)
     return tuple(out)
 
 
-def scope_map(hlo_text: str) -> Dict[str, Tuple[str, ...]]:
-    """Instruction name -> the scopes on its ``op_name`` path, for every
-    instruction of the HLO text.  The compiler leaves some instructions
-    without metadata: a fusion then takes the path of the last
-    instruction of its fused computation that has one (the one nearest
-    the root), and any other instruction (a layout copy, a
+def scope_map(hlo_text: str, scopes: Sequence[str] = SCOPES
+              ) -> Dict[str, Tuple[str, ...]]:
+    """Instruction name -> the scopes of ``scopes`` on its ``op_name``
+    path, for every instruction of the HLO text.  The compiler leaves
+    some instructions without metadata: a fusion then takes the path of
+    the last instruction of its fused computation that has one (the one
+    nearest the root), and any other instruction (a layout copy, a
     ``get-tuple-element``) the path of its first operand; a parameter has
     none, and maps to no scope."""
     paths: Dict[str, Optional[str]] = {}
@@ -144,23 +148,14 @@ def scope_map(hlo_text: str) -> Dict[str, Tuple[str, ...]]:
     out = {}
     for name in paths:
         p = path(name)
-        out[name] = path_scopes(p) if p else ()
+        out[name] = path_scopes(p, scopes) if p else ()
     return out
 
 
-def load_host(path: str, names=HOST_SPANS) -> List[Tuple[str, float, float]]:
-    """The host's annotation spans named in ``names``, sorted by start."""
-    from jax.profiler import ProfileData
-
-    host = []
-    for plane in ProfileData.from_file(path).planes:
-        if plane.name.startswith("/host:"):
-            for line in plane.lines:
-                for ev in line.events:
-                    if ev.name in names:
-                        host.append((ev.name, ev.start_ns,
-                                     ev.start_ns + ev.duration_ns))
-    return sorted(host, key=lambda e: e[1])
+def load_host(path: str) -> List[Tuple[str, float, float]]:
+    """The host's spans of ``HOST_SPANS`` in a raw trace, sorted by
+    start."""
+    return trace_reduce.load(path).host
 
 
 def window(trace: trace_reduce.Trace) -> Optional[Tuple[float, float]]:
@@ -225,6 +220,31 @@ def per_epoch_ms(red: Dict[int, trace_reduce.ChipReduction],
     return max(per) * 1e-6 if per else None
 
 
+def scope_ms(red: Dict[int, trace_reduce.ChipReduction],
+             scopes: Dict[int, Dict[str, float]], names: Sequence[str]
+             ) -> Dict[str, float]:
+    """``per_epoch_ms`` of each scope of ``names`` that ran."""
+    out = {}
+    for sc in names:
+        ms = per_epoch_ms(red, scopes, sc)
+        if ms is not None:
+            out[sc] = ms
+    return out
+
+
+def trace_context(trace: trace_reduce.Trace,
+                  red: Dict[int, trace_reduce.ChipReduction],
+                  hlo_text: str, extra: Sequence[str] = ()
+                  ) -> Dict[str, object]:
+    """What the metric readers get of a traced window beside its
+    reduction: ``scope_ms``, each scope's device time per epoch on the
+    slowest chip over ``SCOPES`` and the model's ``extra`` scopes, from
+    the epoch program's HLO text; ``host``, the host's spans."""
+    names = tuple(SCOPES) + tuple(s for s in extra if s not in SCOPES)
+    scopes = scope_ns(trace, red, scope_map(hlo_text, names))
+    return {"scope_ms": scope_ms(red, scopes, names), "host": trace.host}
+
+
 def host_turnaround_ns(host) -> Optional[float]:
     """Mean over consecutive epochs of (next ``dispatch`` start − this
     ``readback`` end)."""
@@ -284,8 +304,9 @@ def reduce_phases(trace: trace_reduce.Trace, host, hlo_text: str
         return {}
     smap = scope_map(hlo_text)
     scopes = scope_ns(trace, red, smap)
+    ms = scope_ms(red, scopes, SCOPES)
     out: Dict[str, object] = {
-        k: per_epoch_ms(red, scopes, sc) for k, sc in SCOPE_METRICS.items()}
+        k: ms.get(sc) for k, sc in SCOPE_METRICS.items()}
     turn = host_turnaround_ns(host)
     out["host_turnaround_ms"] = None if turn is None else turn * 1e-6
     slow = max(red, key=lambda i: red[i].step_ns)
@@ -314,9 +335,6 @@ def measure(cell, seed: int, seconds: float, devices,
     numbers, the epoch program's memory analysis and tracing's cost.
     ``keep``: a path prefix for the raw trace (``.xplane.pb``) and the
     program's HLO text (``.hlo.txt``)."""
-    import shutil
-    import tempfile
-
     from benchmarks.chip import harness
 
     fed = harness.Federation(cell.config, cell.traffic)
@@ -327,14 +345,9 @@ def measure(cell, seed: int, seconds: float, devices,
     state, records, elapsed = harness.window(
         fed, state, harness.CHECK_STEPS, batch_fn, seconds)
     epoch0 = harness.CHECK_STEPS + len(records)
-    tmp = tempfile.mkdtemp(prefix="chipbench-phases-")
-    try:
-        raw = (keep or os.path.join(tmp, "trace")) + ".xplane.pb"
-        state, traced, trace = harness.traced_epochs(fed, state, epoch0,
-                                                     batch_fn, raw)
-        host = load_host(raw)
-    finally:
-        shutil.rmtree(tmp, ignore_errors=True)
+    state, traced, trace = harness.traced_epochs(
+        fed, state, epoch0, batch_fn, keep and keep + ".xplane.pb")
+    host = trace.host
     program = fed.engine.epoch_program(
         state, epoch0 + len(traced), batch_fn)
     hlo = program.as_text()

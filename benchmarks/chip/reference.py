@@ -1,28 +1,28 @@
 """Plain reference of the first epochs of a DFL training cell.
 
 Written from the published descriptions alone and importing nothing of the
-trainer under test: a Llama-style decoder (RMSNorm, rotary attention with
-grouped key/value heads, SiLU-gated MLP, tied or untied head) with
-next-token cross-entropy, SGD on every client of the ``(M, N)`` grid,
-Eq.-4 aggregation (mean over a server's clients), and T_S gossip rounds
-``W <- A W`` with the graph's Metropolis weights.  Where the configuration
-ships gossip over the int8 physical wire, each server's message is the
-error-feedback corrected model, each round is delta-coded against the
-receivers' decoded copy, and the codes are int8 with one absmax scale per
-256-element chunk of the whole flattened model (stochastic rounding); the
-residual is what round 0 withheld.
+trainer under test: the configuration's model, from its plug-in
+(``models/``: the block and its next-token cross-entropy), SGD on every
+client of the ``(M, N)`` grid, Eq.-4 aggregation (mean over a server's
+clients), and T_S gossip rounds ``W <- A W`` with the graph's Metropolis
+weights.  Where the configuration ships gossip over the int8 physical
+wire, each server's message is the error-feedback corrected model, each
+round is delta-coded against the receivers' decoded copy, and the codes
+are int8 with one absmax scale per 256-element chunk of the whole
+flattened model (stochastic rounding); the residual is what round 0
+withheld.
 
 It runs in float32 with ``highest`` matmul precision.  ``dtype=bfloat16``
 computes the same epochs in bfloat16 throughout: that is the control that
 the comparison has to reject.
 
-The weights come from ``init_weights``, which the benchmark also hands to
-the trainer, so both start from the same seeded model; the tokens come
-from ``traffic.epoch_tokens``.
+The weights come from the plug-in's ``init_weights``, which the benchmark
+also hands to the trainer, so both start from the same seeded model; the
+tokens come from ``traffic.epoch_tokens``.
 """
 from __future__ import annotations
 
-import math
+from types import ModuleType
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
@@ -36,139 +36,24 @@ QMAX = 127.0
 WIRE_BLOCK = 1 << 22
 
 
-def dims(cfg: Dict[str, Any]) -> Dict[str, Any]:
-    """The model's sizes under short names, from the configuration file."""
-    h = cfg["num_attention_heads"]
-    return dict(d=cfg["hidden_size"], L=cfg["num_hidden_layers"], h=h,
-                kvh=cfg["num_key_value_heads"],
-                hd=cfg.get("head_dim", cfg["hidden_size"] // h),
-                ff=cfg["intermediate_size"], V=cfg["vocab_size"],
-                eps=cfg["rms_norm_eps"], theta=cfg["rope_theta"],
-                tied=cfg["tie_word_embeddings"],
-                std=cfg["initializer_range"])
-
-
 def seed_key(seed: int) -> jax.Array:
     """A PRNG key for any non-negative seed, also one past 32 bits."""
     return jax.random.fold_in(jax.random.key(seed % (1 << 31)),
                               seed >> 31)
 
 
-def weight_shapes(cfg: Dict[str, Any]) -> Dict[str, Any]:
-    """Leaf shapes in the trainer's parameter layout: ``stack`` holds one
-    block whose leaves carry a leading layer axis."""
-    m = dims(cfg)
-    d, L, h, kvh, hd, ff, V = (m[k] for k in
-                               ("d", "L", "h", "kvh", "hd", "ff", "V"))
-    shapes = {
-        "embed": (V, d),
-        "final_norm": {"scale": (d,)},
-        "stack": ({
-            "ffn": {"down": (L, ff, d), "gate": (L, d, ff),
-                    "up": (L, d, ff)},
-            "ln1": {"scale": (L, d)},
-            "ln2": {"scale": (L, d)},
-            "mixer": {"w_k": (L, d, kvh, hd), "w_o": (L, h, hd, d),
-                      "w_q": (L, d, h, hd), "w_v": (L, d, kvh, hd)},
-        },),
-    }
-    if not m["tied"]:
-        shapes["head"] = (d, V)
-    return shapes
-
-
-def _is_shape(x) -> bool:
-    return isinstance(x, tuple) and all(isinstance(i, int) for i in x)
-
-
-def init_weights(key: jax.Array, cfg: Dict[str, Any]) -> Any:
-    """Normal(0, initializer_range) matrices and unit norm scales, float32.
-    Jit it whole: one program makes the model on the device."""
-    std = dims(cfg)["std"]
-    shapes = weight_shapes(cfg)
-    paths = jax.tree_util.tree_flatten_with_path(shapes, is_leaf=_is_shape)
-    leaves = []
-    for i, (path, shape) in enumerate(paths[0]):
-        if getattr(path[-1], "key", None) == "scale":
-            leaves.append(jnp.ones(shape, jnp.float32))
-        else:
-            leaves.append(std * jax.random.normal(jax.random.fold_in(key, i),
-                                                  shape, jnp.float32))
-    return jax.tree.unflatten(paths[1], leaves)
-
-
 # ---------------------------------------------------------------------------
-# the model
+# the local period
 # ---------------------------------------------------------------------------
 
 
-def _rmsnorm(x, scale, eps):
-    var = jnp.mean(x * x, axis=-1, keepdims=True)
-    return x * jax.lax.rsqrt(var + jnp.asarray(eps, x.dtype)) * scale
-
-
-def _rope(x, theta):
-    """Rotary embedding, ``x``: (b, s, heads, hd); rotate-half form."""
-    s, hd = x.shape[1], x.shape[-1]
-    inv = 1.0 / (theta ** (np.arange(0, hd, 2, dtype=np.float64) / hd))
-    ang = np.arange(s, dtype=np.float64)[:, None] * inv[None, :]
-    cos = np.concatenate([np.cos(ang)] * 2, axis=-1)[None, :, None, :]
-    sin = np.concatenate([np.sin(ang)] * 2, axis=-1)[None, :, None, :]
-    half = hd // 2
-    rot = jnp.concatenate([-x[..., half:], x[..., :half]], axis=-1)
-    return x * jnp.asarray(cos, x.dtype) + rot * jnp.asarray(sin, x.dtype)
-
-
-def _layer(x, p, m):
-    h = _rmsnorm(x, p["ln1"]["scale"], m["eps"])
-    a = p["mixer"]
-    q = _rope(jnp.einsum("bsd,dhk->bshk", h, a["w_q"]), m["theta"])
-    k = _rope(jnp.einsum("bsd,dhk->bshk", h, a["w_k"]), m["theta"])
-    v = jnp.einsum("bsd,dhk->bshk", h, a["w_v"])
-    rep = m["h"] // m["kvh"]
-    k = jnp.repeat(k, rep, axis=2)
-    v = jnp.repeat(v, rep, axis=2)
-    s = x.shape[1]
-    scores = jnp.einsum("bqhk,bshk->bhqs", q, k) / jnp.asarray(
-        math.sqrt(m["hd"]), x.dtype)
-    causal = np.tril(np.ones((s, s), bool))
-    scores = jnp.where(causal, scores, jnp.asarray(-1e30, x.dtype)
-                       if x.dtype == jnp.float32
-                       else jnp.finfo(x.dtype).min)
-    probs = jax.nn.softmax(scores, axis=-1)
-    att = jnp.einsum("bhqs,bshk->bqhk", probs, v)
-    x = x + jnp.einsum("bqhk,hkd->bqd", att, a["w_o"])
-    h = _rmsnorm(x, p["ln2"]["scale"], m["eps"])
-    f = p["ffn"]
-    g = jax.nn.silu(jnp.einsum("bsd,df->bsf", h, f["gate"]))
-    u = jnp.einsum("bsd,df->bsf", h, f["up"])
-    return x + jnp.einsum("bsf,fd->bsd", g * u, f["down"])
-
-
-def loss(w, tokens, m):
-    """Mean next-token cross-entropy of ``tokens`` (b, s) under ``w``."""
-    x = w["embed"][tokens]
-
-    def body(x, p):
-        return _layer(x, p, m), None
-
-    x, _ = jax.lax.scan(body, x, w["stack"][0])
-    x = _rmsnorm(x, w["final_norm"]["scale"], m["eps"])
-    head = w["embed"].T if m["tied"] else w["head"]
-    logits = jnp.einsum("bsd,dv->bsv", x[:, :-1], head)
-    tgt = tokens[:, 1:]
-    lse = jax.nn.logsumexp(logits, axis=-1)
-    picked = jnp.take_along_axis(logits, tgt[..., None], axis=-1)[..., 0]
-    return jnp.mean(lse - picked)
-
-
-def client_period(w, tokens, gamma, m):
-    """T_C SGD steps of one client; ``tokens`` (T_C, b, s).  Returns the
-    new weights and the loss of the last step."""
+def client_period(w, tokens, gamma, loss):
+    """T_C SGD steps of one client on ``loss(w, tokens)``; ``tokens``
+    (T_C, b, s).  Returns the new weights and the loss of the last step."""
     grad = jax.value_and_grad(loss)
 
     def step(w, tok):
-        val, g = grad(w, tok, m)
+        val, g = grad(w, tok)
         w = jax.tree.map(lambda p, gp: p - jnp.asarray(gamma, p.dtype) * gp,
                          w, g)
         return w, val
@@ -244,12 +129,12 @@ def wire_block(w, res, a, key, t_server: int):
 
 
 class Reference:
-    """The reference federation for one cell; ``run`` replays its first
-    epochs on the same weights and tokens the trainer got."""
+    """The reference federation for one cell and its model plug-in;
+    ``run`` replays its first epochs on the same weights and tokens the
+    trainer got."""
 
     def __init__(self, fed: Dict[str, Any], traffic: Dict[str, Any],
-                 dtype=jnp.float32):
-        self.m = dims(fed)
+                 model: ModuleType, dtype=jnp.float32):
         self.fed = fed
         self.traffic = traffic
         self.dtype = dtype
@@ -260,9 +145,8 @@ class Reference:
                           or not fed["error_feedback"]):
             raise ValueError("the reference's wire is int8, physical, with "
                              "error feedback")
-        m = self.m
-        self._period = jax.jit(
-            lambda w, tok: client_period(w, tok, fed["gamma"], m))
+        self._period = jax.jit(lambda w, tok: client_period(
+            w, tok, fed["gamma"], lambda w, t: model.loss(w, t, fed)))
         t_s = traffic["t_server"]
         self._wire = jax.jit(
             lambda w, r, a, k: wire_block(w, r, a, k, t_s),

@@ -2,8 +2,9 @@
 
 ``load(path)`` reads an ``.xplane.pb`` with ``jax.profiler.ProfileData``
 into plain lists: per chip the ``XLA Ops`` and ``XLA Modules`` events, and
-the host's ``TraceAnnotation`` spans that the harness opens (``engine``
-around each ``run_epoch`` call, ``input`` around each batch).  ``reduce``
+the host's ``TraceAnnotation`` spans: those that the harness opens
+(``engine`` around each ``run_epoch`` call, ``input`` around each batch)
+and the engine's own phases inside ``run_epoch``.  ``reduce``
 then gives, per chip: busy time (the union of op intervals inside the
 window), idle share, the epoch program's device time, the idle gap between
 one epoch program and the next, collective time, and a breakdown of the
@@ -18,6 +19,10 @@ import re
 from typing import Dict, List, Optional, Sequence, Tuple
 
 ANNOTATIONS = ("engine", "input")
+# the engine's host spans (core/engine.py run_epoch)
+ENGINE_SPANS = ("epoch", "fault-surgery", "schedule", "batch", "dispatch",
+                "readback", "host-aggregation")
+HOST_SPANS = ANNOTATIONS + ENGINE_SPANS
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 COLLECTIVE = re.compile(r"all-gather|all-reduce|reduce-scatter|"
                         r"collective-permute|all-to-all", re.I)
@@ -65,7 +70,7 @@ def load(path: str) -> Trace:
         elif plane.name.startswith("/host:"):
             for line in plane.lines:
                 for ev in line.events:
-                    if ev.name in ANNOTATIONS:
+                    if ev.name in HOST_SPANS:
                         host.append((ev.name, ev.start_ns,
                                      ev.start_ns + ev.duration_ns))
     return Trace(chips, sorted(host, key=lambda e: e[1]))

@@ -1,8 +1,10 @@
-"""The benchmark's FLOPs function and peaks table."""
+"""The benchmark's FLOPs count and peaks table."""
 import pytest
 
 from bench_tiny import harness  # noqa: F401  (puts the repo on sys.path)
-from benchmarks.chip import flops
+from benchmarks.chip import flops, models
+
+LLAMA = models.load({})
 
 
 def _cfg(layers):
@@ -19,11 +21,13 @@ def test_flops_per_token_is_the_hand_count(layers):
     head = 49152 * 960                       # tied: counted once
     attn = 12 * layers * 15 * 64 * 256       # scores and values at S=256
     want = 6 * (layers * per_layer + head) + attn
-    assert flops.train_flops_per_token(_cfg(layers), 256) == want
+    assert LLAMA.train_flops_per_token(_cfg(layers), 256) == want
     if layers == 32:
         # the 32-layer count: 361,821,120 parameters less 62,400 in norms
-        assert flops.matmul_params(_cfg(32)) == 361_821_120 - 62_400
+        assert LLAMA.matmul_params(_cfg(32)) == 361_821_120 - 62_400
         assert want == 2_264_924_160
+    else:
+        assert want == 778_567_680
 
 
 def test_unknown_device_kind_raises():
