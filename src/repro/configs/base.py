@@ -28,6 +28,25 @@ class MoEConfig:
     router_aux_weight: float = 0.01  # load-balance loss weight (kept client-local)
     # which decoder layers are MoE: "all" | "every_2" | "all_but_first"
     layer_pattern: str = "all"
+    # the routed experts this device holds: ``num_held_experts`` of them
+    # from ``first_held_expert`` (None: all).  The router keeps every
+    # output; token-slots routed to an expert held elsewhere add nothing
+    # here (one device's share of an expert-parallel layer).
+    num_held_experts: Optional[int] = None
+    first_held_expert: int = 0
+    # gate weights: the top-k router probabilities, renormalised to sum to
+    # one when ``norm_topk_prob``, times ``routed_scaling_factor``
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 1.0
+    # balance loss per sequence, averaged over the batch (DeepSeek's
+    # ``seq_aux``), instead of over the whole batch at once
+    seq_aux: bool = False
+
+    @property
+    def held(self) -> int:
+        """How many routed experts this device holds."""
+        return (self.num_experts if self.num_held_experts is None
+                else self.num_held_experts)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -35,10 +54,27 @@ class MLAConfig:
     """DeepSeek-V2 Multi-head Latent Attention."""
 
     kv_lora_rank: int = 512
-    q_lora_rank: int = 1536
+    q_lora_rank: Optional[int] = 1536   # None: a direct query projection
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
     v_head_dim: int = 128
+
+
+@dataclasses.dataclass(frozen=True)
+class YarnScaling:
+    """YaRN rotary scaling (DeepSeek-V2's ``rope_scaling`` of type "yarn"):
+    frequencies between the two correction dimensions are blended from
+    interpolated (divided by ``factor``) to extrapolated along a linear
+    ramp, cos/sin are multiplied by mscale(mscale) / mscale(mscale_all_dim)
+    and the softmax scale by mscale(mscale_all_dim) squared, where
+    mscale(m) = 0.1 m ln(factor) + 1."""
+
+    factor: float = 40.0
+    original_max_position_embeddings: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 0.707
+    mscale_all_dim: float = 0.707
 
 
 @dataclasses.dataclass(frozen=True)
@@ -103,6 +139,7 @@ class ArchConfig:
     #   "global" (full causal), "local" (sliding window), "mamba"
     layer_pattern: Sequence[str] = ("global",)
     rope_theta: float = 10_000.0
+    rope_scaling: Optional[YarnScaling] = None   # MLA's rope dimensions
     use_bias: bool = False
     tie_embeddings: bool = False
     norm_eps: float = 1e-6
@@ -160,7 +197,10 @@ def _attn_params(cfg: ArchConfig) -> int:
     if cfg.mla is not None:
         m = cfg.mla
         q_head = m.qk_nope_head_dim + m.qk_rope_head_dim
-        p = d * m.q_lora_rank + m.q_lora_rank * cfg.num_heads * q_head          # q down/up
+        if m.q_lora_rank is None:
+            p = d * cfg.num_heads * q_head                                      # q
+        else:
+            p = d * m.q_lora_rank + m.q_lora_rank * cfg.num_heads * q_head      # q down/up
         p += d * (m.kv_lora_rank + m.qk_rope_head_dim)                          # kv down (+shared rope)
         p += m.kv_lora_rank * cfg.num_heads * (m.qk_nope_head_dim + m.v_head_dim)  # kv up
         p += cfg.num_heads * m.v_head_dim * d                                    # out proj
@@ -200,7 +240,7 @@ def _block_params(cfg: ArchConfig, idx: int, active_only: bool) -> int:
         p += _attn_params(cfg)
     if cfg.is_moe_layer(idx):
         moe = cfg.moe
-        n_live = (moe.top_k if active_only else moe.num_experts)
+        n_live = (moe.top_k if active_only else moe.held)
         p += n_live * _mlp_params(d, moe.d_ff_expert)
         p += moe.num_shared_experts * _mlp_params(d, moe.d_ff_shared or moe.d_ff_expert)
         p += d * moe.num_experts  # router
@@ -257,6 +297,7 @@ ARCH_IDS = (
     "qwen3_1_7b",
     "mamba2_780m",
     "deepseek_v2_236b",
+    "deepseek_v2_lite",
 )
 
 

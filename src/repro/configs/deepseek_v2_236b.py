@@ -1,5 +1,13 @@
 """DeepSeek-V2 236B (21B active) [arXiv:2405.04434] — MLA (kv_lora 512) +
-160 routed experts top-6 + 2 shared experts; dense first layer (d_ff 12288)."""
+160 routed experts top-6 + 2 shared experts; dense first layer (d_ff 12288).
+
+Not computed: the published router is ``group_limited_greedy`` (the 160
+experts in n_group 8 device groups, top-k taken within the topk_group 3
+best groups) with ``routed_scaling_factor`` 16; this configuration routes
+greedily over all experts, renormalises the top-k and scales by 1, and
+carries no YaRN rotary scaling.  Its rotary de-interleaves the rope
+dimensions before rotate-half, as the published code does (every MLA
+layer here does)."""
 import dataclasses
 
 from repro.configs.base import ArchConfig, MLAConfig, MoEConfig

@@ -60,7 +60,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Callable, NamedTuple, Optional, Tuple
+from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -114,6 +114,11 @@ class DFLMetrics(NamedTuple):
     # observer is attached — so obs on/off runs the same compiled program);
     # None everywhere else.
     screen_rejected: Optional[jax.Array] = None
+    # the loss's aux dict at the last local step: each scalar entry as an
+    # (M, N) array per client (the LM loss: ``nll``, ``aux``, and for an
+    # MoE model ``moe_load_max``); the engine records each entry's worst
+    # (largest) participating client
+    aux: Optional[Dict[str, jax.Array]] = None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -542,7 +547,7 @@ def build_dfl_epoch_step(
         rng, sub = jax.random.split(rng)
         keys = jax.random.split(sub, (m, n))  # typed keys: pass jax.random.key()
         if n_micro == 1:
-            (loss, _aux), grads = client_grad(params, batch_t, keys)
+            (loss, aux), grads = client_grad(params, batch_t, keys)
         else:
             # split the per-client batch dim (axis 2 after (M, N)) into
             # n_micro sequential microbatches; average the gradients.
@@ -559,15 +564,17 @@ def build_dfl_epoch_step(
             # each microgradient by 1/n first keeps bf16 accumulation well-
             # conditioned (grads are same-scale summands).
             def micro_step(g_acc, mb):
-                (mloss, _maux), g = client_grad(params, mb, keys)
+                (mloss, maux), g = client_grad(params, mb, keys)
                 g_acc = jax.tree.map(
                     lambda a, x: a + (x / n_micro).astype(a.dtype), g_acc, g)
-                return g_acc, mloss
+                return g_acc, (mloss, client_scalars(maux))
 
             g0 = jax.tree.map(lambda p: jnp.zeros(p.shape, cfg.param_dtype),
                               params)
-            grads, mlosses = jax.lax.scan(micro_step, g0, micro_batches)
+            grads, (mlosses, maux) = jax.lax.scan(micro_step, g0,
+                                                  micro_batches)
             loss = mlosses.mean(axis=0)
+            aux = jax.tree.map(lambda a: a.mean(axis=0), maux)
         with jax.named_scope("sgd_update"):
             params, opt_state = optimizer.update(grads, opt_state, params)
         if cfg.metrics == "full":
@@ -575,7 +582,14 @@ def build_dfl_epoch_step(
                 gnorm = jnp.sqrt(_tree_sq_norm(grads) / (m * n))
         else:
             gnorm = jnp.zeros((), jnp.float32)
-        return (params, opt_state, rng), (loss, gnorm)
+        return (params, opt_state, rng), (loss, gnorm, client_scalars(aux))
+
+    def client_scalars(aux):
+        """The loss aux dict's entries that hold one scalar per client."""
+        if not isinstance(aux, dict):
+            return {}
+        return {k: v.astype(jnp.float32) for k, v in aux.items()
+                if getattr(v, "shape", None) == (m, n)}
 
     def apply_consensus(server_tree, a_p=None, psum_weight=None,
                         ef_residual=None, key=None, lam2=None):
@@ -636,7 +650,7 @@ def build_dfl_epoch_step(
         # ---- 1. local period: T_C client SGD iterations (Eq. 3) ----
         carry = (state.client_params, state.opt_state, state.rng)
         with jax.named_scope("local_period"):
-            (params, opt_state, rng), (losses, gnorms) = jax.lax.scan(
+            (params, opt_state, rng), (losses, gnorms, auxs) = jax.lax.scan(
                 local_step, carry, batches)
 
         # ---- 2. aggregation at each server (Eq. 4) ----
@@ -663,7 +677,8 @@ def build_dfl_epoch_step(
                              ef_res)
         metrics = DFLMetrics(loss=losses, server_disagreement=disagreement,
                              client_drift=drift, grad_norm=gnorms[-1],
-                             screen_rejected=screen)
+                             screen_rejected=screen,
+                             aux=jax.tree.map(lambda a: a[-1], auxs))
         return new_state, metrics
 
     def epoch_step_dynamic(state: DFLState, batches: Any,
@@ -678,7 +693,7 @@ def build_dfl_epoch_step(
         # independent during the local period) and keeps the scan dense.
         carry = (state.client_params, state.opt_state, state.rng)
         with jax.named_scope("local_period"):
-            (params, opt_state, rng), (losses, gnorms) = jax.lax.scan(
+            (params, opt_state, rng), (losses, gnorms, auxs) = jax.lax.scan(
                 local_step, carry, batches)
         # ---- 2. masked aggregation (Eq. 4 over the participating set);
         # non-participants carry their broadcast model (and optimizer
@@ -716,7 +731,8 @@ def build_dfl_epoch_step(
                              ef_res)
         metrics = DFLMetrics(loss=losses, server_disagreement=disagreement,
                              client_drift=drift, grad_norm=gnorms[-1],
-                             screen_rejected=screen)
+                             screen_rejected=screen,
+                             aux=jax.tree.map(lambda a: a[-1], auxs))
         return new_state, metrics
 
     return epoch_step_dynamic if cfg.dynamic else epoch_step

@@ -508,6 +508,7 @@ class DynamicFederationEngine:
                         / rounds)
                     record["screen_rejected"] = float(
                         screen_per_round.sum())
+                record.update(worst_client(metrics_h.aux, mask_np))
                 obs.observe(
                     epoch, record, servers=tuple(self.alive),
                     per_link=(self._bytes.per_link
@@ -628,6 +629,9 @@ class DynamicFederationEngine:
                                        np.float32) / rounds)
                         record["screen_rejected"] = float(
                             screen_per_round.sum())
+                    record.update(worst_client(
+                        jax.tree.map(lambda x, i=i: x[i], metrics_h.aux),
+                        mask_np))
                     records.append((record, screen_per_round))
                 for i, (record, screen_per_round) in enumerate(records):
                     obs.observe(
@@ -651,6 +655,18 @@ class DynamicFederationEngine:
                 for key, v in rec.items():
                     history.setdefault(key, []).append(v)
         return state, history
+
+
+def worst_client(aux: Optional[Dict[str, Any]], mask: np.ndarray
+                 ) -> Dict[str, float]:
+    """Each entry of the loss's per-client aux scalars (``DFLMetrics.aux``,
+    (M, N) each) at its largest over the participating clients (over all
+    of them when none participates)."""
+    if not aux:
+        return {}
+    live = mask > 0 if mask.any() else np.ones(mask.shape, bool)
+    return {k: float(np.asarray(v, np.float32)[live].max())
+            for k, v in aux.items()}
 
 
 def _key_data(state: dfl.DFLState) -> dfl.DFLState:

@@ -111,10 +111,13 @@ PLANS: Dict[str, DeploymentPlan] = {
                                    batch_over_model=True),
     "seamless_m4t_large_v2": DeploymentPlan("seamless_m4t_large_v2",
                                             _SMALL_SP, _SMALL_MP),
-    # ~27-35B: bf16 + R=2 (1.7-1.9 GB params/device)
+    # ~16-35B: bf16 + R=2 (1.7-1.9 GB params/device; deepseek-v2-lite 0.5)
     "gemma2_27b": DeploymentPlan("gemma2_27b", _MID_SP, _MID_MP,
                                  param_dtype="bfloat16",
                                  grad_microbatches=8, serve_fsdp=True),
+    "deepseek_v2_lite": DeploymentPlan("deepseek_v2_lite", _MID_SP, _MID_MP,
+                                       param_dtype="bfloat16",
+                                       grad_microbatches=8, serve_fsdp=True),
     "command_r_35b": DeploymentPlan("command_r_35b", _MID_SP, _MID_MP,
                                     param_dtype="bfloat16",
                                     grad_microbatches=16, serve_fsdp=True,

@@ -27,6 +27,7 @@ import numpy as np
 
 from repro.checkpoint import Checkpointer
 from repro.configs import get_arch, get_smoke
+from repro.core.engine import worst_client
 from repro.core import (DFLConfig, FLTopology, build_dfl_epoch_step,
                         init_dfl_state, make_engine, ByzantineSchedule,
                         FaultSchedule, ParticipationSchedule, SigmaTracker,
@@ -234,6 +235,8 @@ def train(arch_id: str, *, smoke: bool = True, servers: int = 2,
         if ledger is not None:
             record["wire_mb"] = ledger.update() / 1e6
             record["wire_ratio"] = ledger.tracker.ratio()
+        record.update(worst_client(jax.device_get(metrics.aux),
+                                   np.ones((servers, clients))))
         return record
 
     def ckpt_save(epoch: int) -> None:

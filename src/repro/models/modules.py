@@ -19,7 +19,7 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.configs.base import ArchConfig
+from repro.configs.base import ArchConfig, YarnScaling
 
 
 def _dense_init(key, shape, dtype, scale: Optional[float] = None):
@@ -57,18 +57,51 @@ def softcap(x: jax.Array, cap: Optional[float]) -> jax.Array:
 # ---------------------------------------------------------------------------
 
 
-def rope_frequencies(head_dim: int, theta: float) -> jax.Array:
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's magnitude correction 0.1 m ln(factor) + 1 (1 below factor 1)."""
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def yarn_correction_range(scaling: YarnScaling, dim: int, theta: float
+                          ) -> Tuple[int, int]:
+    """The rotary dimensions (of ``dim // 2`` frequencies) between which YaRN
+    ramps from extrapolated to interpolated frequencies: where a frequency
+    turns ``beta_fast`` and ``beta_slow`` times over the original context,
+    floored and ceiled, clipped to ``[0, dim - 1]``."""
+    def dim_at(rotations):
+        return (dim * math.log(scaling.original_max_position_embeddings
+                               / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+    return (max(math.floor(dim_at(scaling.beta_fast)), 0),
+            min(math.ceil(dim_at(scaling.beta_slow)), dim - 1))
+
+
+def rope_frequencies(head_dim: int, theta: float,
+                     scaling: Optional[YarnScaling] = None) -> jax.Array:
     half = head_dim // 2
-    return 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
+    freqs = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
+    if scaling is None:
+        return freqs
+    low, high = yarn_correction_range(scaling, head_dim, theta)
+    ramp = jnp.clip((jnp.arange(half, dtype=jnp.float32) - low)
+                    / max(high - low, 0.001), 0.0, 1.0)
+    return freqs / scaling.factor * ramp + freqs * (1.0 - ramp)
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """x: (..., seq, heads, head_dim); positions: broadcastable to (..., seq)."""
-    freqs = rope_frequencies(x.shape[-1], theta)                 # (half,)
+def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
+               scaling: Optional[YarnScaling] = None) -> jax.Array:
+    """x: (..., seq, heads, head_dim); positions: broadcastable to (..., seq).
+    ``scaling``: YaRN frequencies and cos/sin magnitude."""
+    freqs = rope_frequencies(x.shape[-1], theta, scaling)        # (half,)
     angles = positions[..., None].astype(jnp.float32) * freqs    # (..., seq, half)
     cos = jnp.cos(angles)[..., None, :]                          # (..., seq, 1, half)
     sin = jnp.sin(angles)[..., None, :]
-    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+    if scaling is not None:
+        mag = (yarn_mscale(scaling.factor, scaling.mscale)
+               / yarn_mscale(scaling.factor, scaling.mscale_all_dim))
+        cos, sin = cos * mag, sin * mag
+    xf = x.astype(jnp.float32)
+    x1, x2 = jnp.split(xf, 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)
 
@@ -429,10 +462,14 @@ def mla_init(key, cfg: ArchConfig, dtype=jnp.float32) -> Dict:
     d, h = cfg.d_model, cfg.num_heads
     ks = jax.random.split(key, 6)
     qh = m.qk_nope_head_dim + m.qk_rope_head_dim
+    if m.q_lora_rank is None:
+        p = {"w_q": _dense_init(ks[0], (d, h, qh), dtype)}
+    else:
+        p = {"w_dq": _dense_init(ks[0], (d, m.q_lora_rank), dtype),
+             "q_norm": rmsnorm_init(m.q_lora_rank, dtype),
+             "w_uq": _dense_init(ks[1], (m.q_lora_rank, h, qh), dtype)}
     return {
-        "w_dq": _dense_init(ks[0], (d, m.q_lora_rank), dtype),
-        "q_norm": rmsnorm_init(m.q_lora_rank, dtype),
-        "w_uq": _dense_init(ks[1], (m.q_lora_rank, h, qh), dtype),
+        **p,
         "w_dkv": _dense_init(ks[2], (d, m.kv_lora_rank + m.qk_rope_head_dim), dtype),
         "kv_norm": rmsnorm_init(m.kv_lora_rank, dtype),
         "w_ukv": _dense_init(ks[3], (m.kv_lora_rank, h,
@@ -442,35 +479,63 @@ def mla_init(key, cfg: ArchConfig, dtype=jnp.float32) -> Dict:
     }
 
 
-def _mla_qkv(params, x, cfg: ArchConfig, positions):
-    """Returns q (b,s,h,qh), latent c_kv (b,s,r), shared k_rope (b,s,rope)."""
+def _mla_rope(x, positions, cfg: ArchConfig):
+    """DeepSeek's published rotary: the rope dimensions de-interleaved
+    (even ones, then odd) before rotate-half."""
+    x = jnp.concatenate([x[..., 0::2], x[..., 1::2]], axis=-1)
+    return apply_rope(x, positions, cfg.rope_theta, cfg.rope_scaling)
+
+
+def mla_softmax_scale(cfg: ArchConfig) -> float:
+    """1/sqrt(query-key head size), times YaRN's mscale(mscale_all_dim)
+    squared under rotary scaling."""
     m = cfg.mla
-    cq = rmsnorm_apply(params["q_norm"], jnp.einsum("bsd,dr->bsr", x, params["w_dq"]),
-                       cfg.norm_eps)
-    q = jnp.einsum("bsr,rhk->bshk", cq, params["w_uq"])
+    scale = 1.0 / math.sqrt(m.qk_nope_head_dim + m.qk_rope_head_dim)
+    rs = cfg.rope_scaling
+    if rs is not None and rs.mscale_all_dim:
+        scale *= yarn_mscale(rs.factor, rs.mscale_all_dim) ** 2
+    return scale
+
+
+def _mla_qkv(params, x, cfg: ArchConfig, positions):
+    """Returns q (b,s,h,qh), latent c_kv (b,s,r), shared k_rope (b,s,rope).
+    The KV down-projection, its norm and the rope split run under the
+    ``latent_kv`` named scope."""
+    m = cfg.mla
+    if "w_q" in params:
+        q = jnp.einsum("bsd,dhk->bshk", x, params["w_q"])
+    else:
+        cq = rmsnorm_apply(params["q_norm"],
+                           jnp.einsum("bsd,dr->bsr", x, params["w_dq"]),
+                           cfg.norm_eps)
+        q = jnp.einsum("bsr,rhk->bshk", cq, params["w_uq"])
     q_nope, q_rope = jnp.split(q, [m.qk_nope_head_dim], axis=-1)
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
-    dkv = jnp.einsum("bsd,dr->bsr", x, params["w_dkv"])
-    c_kv, k_rope = jnp.split(dkv, [m.kv_lora_rank], axis=-1)
-    c_kv = rmsnorm_apply(params["kv_norm"], c_kv, cfg.norm_eps)
-    k_rope = apply_rope(k_rope[:, :, None, :], positions, cfg.rope_theta)[:, :, 0]
+    q_rope = _mla_rope(q_rope, positions, cfg)
+    with jax.named_scope("latent_kv"):
+        dkv = jnp.einsum("bsd,dr->bsr", x, params["w_dkv"])
+        c_kv, k_rope = jnp.split(dkv, [m.kv_lora_rank], axis=-1)
+        c_kv = rmsnorm_apply(params["kv_norm"], c_kv, cfg.norm_eps)
+        k_rope = _mla_rope(k_rope[:, :, None, :], positions, cfg)[:, :, 0]
     return jnp.concatenate([q_nope, q_rope], -1), c_kv, k_rope
 
 
 def _mla_attend(params, q, c_kv, k_rope, mask, cfg: ArchConfig,
                 causal: Optional[bool] = None, head_sharding=None):
-    """Expand latent to per-head K/V and attend (naive/faithful path).
+    """Expand latent to per-head K/V (under ``latent_kv``) and attend
+    (naive/faithful path).
 
     ``mask`` is used for decode (ring-buffer validity); full-sequence
     callers pass ``causal=True`` and route through ``dispatch_attend`` so
     32k prefill never materializes (sq, sk) scores."""
     m = cfg.mla
-    ukv = jnp.einsum("bsr,rhk->bshk", c_kv, params["w_ukv"])
-    k_nope, v = jnp.split(ukv, [m.qk_nope_head_dim], axis=-1)
-    k = jnp.concatenate(
-        [k_nope, jnp.broadcast_to(k_rope[:, :, None, :],
-                                  k_nope.shape[:3] + (m.qk_rope_head_dim,))], -1)
-    scale = 1.0 / math.sqrt(m.qk_nope_head_dim + m.qk_rope_head_dim)
+    with jax.named_scope("latent_kv"):
+        ukv = jnp.einsum("bsr,rhk->bshk", c_kv, params["w_ukv"])
+        k_nope, v = jnp.split(ukv, [m.qk_nope_head_dim], axis=-1)
+        k = jnp.concatenate(
+            [k_nope, jnp.broadcast_to(k_rope[:, :, None, :],
+                                      k_nope.shape[:3] + (m.qk_rope_head_dim,))],
+            -1)
+    scale = mla_softmax_scale(cfg)
     if causal is not None:
         out = dispatch_attend(q, k, v, causal=causal, window=None,
                               attn_softcap=None, scale=scale,
@@ -534,7 +599,7 @@ def _mla_attend_absorbed(params, q, c_kv, k_rope, mask, cfg: ArchConfig):
     # q_lat[b,t,h,r] = q_nope . W_UK^T : query expressed in latent space
     q_lat = jnp.einsum("bthk,rhk->bthr", q_nope.astype(jnp.float32),
                        w_uk.astype(jnp.float32))
-    scale = 1.0 / math.sqrt(m.qk_nope_head_dim + m.qk_rope_head_dim)
+    scale = mla_softmax_scale(cfg)
     scores = (jnp.einsum("bthr,bsr->bhts", q_lat, c_kv.astype(jnp.float32))
               + jnp.einsum("bthk,bsk->bhts", q_rope.astype(jnp.float32),
                            k_rope.astype(jnp.float32))) * scale
@@ -570,12 +635,14 @@ def mlp_apply(params: Dict, x: jax.Array, act: str = "silu") -> jax.Array:
 
 
 def moe_init(key, cfg: ArchConfig, dtype=jnp.float32) -> Dict:
+    """The router over every routed expert, the held experts' weights
+    (``MoEConfig.held`` of them), and the shared experts as one MLP."""
     moe = cfg.moe
     d = cfg.d_model
     k1, k2, k3, k4, k5 = jax.random.split(key, 5)
-    e, ff = moe.num_experts, moe.d_ff_expert
+    e, ff = moe.held, moe.d_ff_expert
     p = {
-        "router": _dense_init(k1, (d, e), dtype, scale=0.02),
+        "router": _dense_init(k1, (d, moe.num_experts), dtype, scale=0.02),
         "w_gate": _dense_init(k2, (e, d, ff), dtype),
         "w_up": _dense_init(k3, (e, d, ff), dtype),
         "w_down": _dense_init(k4, (e, ff, d), dtype),
@@ -586,87 +653,251 @@ def moe_init(key, cfg: ArchConfig, dtype=jnp.float32) -> Dict:
     return p
 
 
+def moe_route(params: Dict, tokens: jax.Array, cfg: ArchConfig
+              ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """Router of ``tokens`` (..., d): the float32 logits at ``HIGHEST``
+    precision, softmax over every routed expert, greedy top-k.  Returns
+    (probs (..., e), gate weights (..., k), expert ids (..., k)); the gates
+    are the top-k probabilities, renormalised if ``norm_topk_prob``, times
+    ``routed_scaling_factor``."""
+    moe = cfg.moe
+    logits = jnp.einsum("...d,de->...e", tokens.astype(jnp.float32),
+                        params["router"].astype(jnp.float32),
+                        precision=jax.lax.Precision.HIGHEST)
+    probs = jax.nn.softmax(logits, axis=-1)
+    gate_vals, gate_idx = jax.lax.top_k(probs, moe.top_k)
+    if moe.norm_topk_prob:
+        gate_vals = gate_vals / (gate_vals.sum(-1, keepdims=True) + 1e-9)
+    return probs, gate_vals * moe.routed_scaling_factor, gate_idx
+
+
+def _balance_loss(probs: jax.Array, gate_idx: jax.Array, cfg: ArchConfig
+                  ) -> jax.Array:
+    """Load-balance aux loss alpha * sum_e f_e * P_e over all routed experts
+    (f_e: the share of token-slots routed to e times e's count, P_e: the
+    mean router probability), of each row of ``probs`` (n, t, e) and
+    ``gate_idx`` (n, t, k), averaged over the rows."""
+    moe = cfg.moe
+    n, t, e = probs.shape
+    counts = jax.vmap(lambda i: jnp.zeros((e,), jnp.float32).at[
+        i.reshape(-1)].add(1.0))(gate_idx)
+    f = counts * e / (t * moe.top_k)
+    return jnp.mean(jnp.sum(f * probs.mean(1), -1)) * moe.router_aux_weight
+
+
+def _load_max(counts: jax.Array) -> jax.Array:
+    """The largest count of routed token-slots over the mean count."""
+    mean = counts.mean()
+    return jnp.where(mean > 0, counts.max() / jnp.where(mean > 0, mean, 1.0),
+                     0.0).astype(jnp.float32)
+
+
+# rows of one grouped-matmul tile; the slot buffer is padded to a multiple
+GMM_TILE = 128
+# elements of one (k, n) weight tile: 2 MB of bfloat16, 4 MB of the f32
+# accumulator of the weight gradient's kernel
+GMM_TILE_ELEMS = 1 << 20
+
+
+def _gmm_tiling(m: int, k: int, n: int) -> Tuple[int, int, int]:
+    """The kernel's (m, k, n) tiles for one call: ``GMM_TILE`` rows, and the
+    largest (k, n) tile of 128-multiples dividing the operand widths (the
+    width itself under 128) within ``GMM_TILE_ELEMS``.  Each grid step
+    costs a fixed overhead, so at a few dozen rows an expert the weight
+    tile has to be large for the kernel to near the weights' bytes: on a
+    TPU v5e, 8 experts of 2048 x 1408 with 24 rows each took 0.59 ms
+    forward and 1.21 ms forward and backward at 128-wide tiles, 0.21 and
+    0.38 ms at these (the weights' bytes alone: 0.056 ms forward)."""
+    del m
+
+    def widths(dim):
+        return [t for t in range(128, dim + 1, 128) if dim % t == 0] or [dim]
+
+    tk, tn = max(((a, b) for a in widths(k) for b in widths(n)
+                  if a * b <= GMM_TILE_ELEMS),
+                 key=lambda ab: (ab[0] * ab[1], ab[0]),
+                 default=(widths(k)[0], widths(n)[0]))
+    return GMM_TILE, tk, tn
+
+
+def _gmm(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array
+         ) -> jax.Array:
+    """``megablox.gmm`` with f32 accumulation: the Mosaic kernel where the
+    program is lowered for a TPU, the same kernel in interpret mode
+    elsewhere.  Tiles from ``_gmm_tiling``, for the backward's calls too."""
+    from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
+
+    def run(lhs, rhs, group_sizes, interpret):
+        return megablox.gmm(lhs, rhs, group_sizes, jnp.float32, _gmm_tiling,
+                            None, None, False, interpret)
+
+    return jax.lax.platform_dependent(
+        lhs, rhs, group_sizes,
+        tpu=functools.partial(run, interpret=False),
+        default=functools.partial(run, interpret=True))
+
+
+def _gmm_operand_dtype():
+    """bfloat16 operands, what a default-precision float32 matmul computes
+    on the TPU; float32 where the matmul precision asks for more."""
+    prec = jax.config.jax_default_matmul_precision
+    return (jnp.bfloat16 if prec in (None, "default", "bfloat16", "fastest")
+            else jnp.float32)
+
+
+def _moe_grouped(params: Dict, tokens: jax.Array, gates: jax.Array,
+                 gate_idx: jax.Array, cfg: ArchConfig
+                 ) -> Tuple[jax.Array, jax.Array]:
+    """Drop-free routed experts over the held share.  ``tokens`` (t, d),
+    ``gates``/``gate_idx`` (t, k).  Token-slots routed to a held expert are
+    sorted by expert into a static (t*k, d) buffer and the held experts'
+    gate, up and down projections run as grouped matmuls (``experts``
+    scope) over just those rows; slots routed elsewhere sort to the end and
+    add nothing.  Rows past the groups are zeroed before and after each
+    call (the kernel leaves them unwritten).  The sort and the combine run
+    under ``router``.  Returns (y (t, d), counts (held,))."""
+    moe = cfg.moe
+    t, d = tokens.shape
+    k, held = moe.top_k, moe.held
+    rows = t * k
+    m = -(-rows // GMM_TILE) * GMM_TILE
+    dt = _gmm_operand_dtype()
+    with jax.named_scope("router"):
+        local = gate_idx - moe.first_held_expert
+        is_held = (local >= 0) & (local < held)
+        key = jnp.where(is_held, local, held).reshape(-1)       # (t*k,)
+        order = jnp.argsort(key, stable=True)
+        group_sizes = jnp.zeros((held + 1,), jnp.int32).at[key].add(1)[:held]
+        valid = (jnp.arange(m) < jnp.sum(group_sizes))[:, None]
+        xs = jnp.where(valid, tokens[jnp.pad(order // k, (0, m - rows))],
+                       0).astype(dt)
+
+    def mask(x):
+        return jnp.where(valid, x, 0)
+
+    with jax.named_scope("experts"):
+        g = mask(_gmm(xs, params["w_gate"].astype(dt), group_sizes))
+        u = mask(_gmm(xs, params["w_up"].astype(dt), group_sizes))
+        h = mask(_act(g, cfg.act) * u).astype(dt)
+        out = mask(_gmm(h, params["w_down"].astype(dt), group_sizes))
+    with jax.named_scope("router"):
+        # back to (token, slot) order, weighted by the gates, summed
+        slot_out = out[:rows][jnp.argsort(order)].reshape(t, k, d)
+        y = jnp.sum(slot_out * jnp.where(is_held, gates, 0.0)[..., None],
+                    axis=1)
+    return y.astype(tokens.dtype), group_sizes.astype(jnp.float32)
+
+
+def _moe_capacity(params: Dict, tokens: jax.Array, gate_vals: jax.Array,
+                  gate_idx: jax.Array, cfg: ArchConfig,
+                  capacity: int) -> Tuple[jax.Array, jax.Array]:
+    """Capacity dispatch over every expert: ``tokens`` (g, tg, d) in G
+    groups, each expert taking at most ``capacity`` token-slots of a
+    group, the rest dropped.  Returns (y (g, tg, d), load_max)."""
+    moe = cfg.moe
+    g, tg, d = tokens.shape
+    e, k = moe.num_experts, moe.top_k
+    with jax.named_scope("router"):
+        onehot = jax.nn.one_hot(gate_idx, e, dtype=jnp.int32)   # (g, tg, k, e)
+        load = _load_max(onehot.sum((0, 1, 2)).astype(jnp.float32))
+        # position of each (token, slot) within its expert's per-group buffer
+        flat = onehot.reshape(g, tg * k, e)
+        pos_in_expert = (jnp.cumsum(flat, axis=1) - flat).reshape(g, tg, k, e)
+        pos = (pos_in_expert * onehot).sum(-1)                  # (g, tg, k)
+        keep = pos < capacity
+        gate_vals = gate_vals * keep
+
+        # Gather-based dispatch: scatter only TOKEN INDICES (tiny, s32) into
+        # the (g, e, cap) slot table, then build expert inputs with a
+        # batched gather.  Scatter-adding full token VECTORS into (g, e,
+        # cap, d) defeats the SPMD partitioner (it replicates the buffer
+        # across all groups — ~21 GB/device for deepseek-v2 at 32k
+        # prefill); the batched gather partitions cleanly along the group
+        # axis.  Slot `capacity` / token id `tg` are the drop sentinels.
+        safe_pos = jnp.where(keep, pos, capacity)               # (g, tg, k)
+        grange = jnp.arange(g)[:, None]
+        token_ids = jnp.broadcast_to(jnp.arange(tg), (g, tg))
+        slot_token = jnp.full((g, e, capacity + 1), tg, jnp.int32)
+        for slot in range(k):                                   # k small/static
+            slot_token = slot_token.at[
+                grange, gate_idx[:, :, slot], safe_pos[:, :, slot]].set(
+                    token_ids)
+        slot_token = slot_token[:, :, :capacity]                # (g, e, cap)
+        tokens_pad = jnp.pad(tokens, ((0, 0), (0, 1), (0, 0)))  # sentinel -> 0
+        expert_in = jnp.take_along_axis(
+            tokens_pad, slot_token.reshape(g, e * capacity)[..., None],
+            axis=1).reshape(g, e, capacity, d)
+    with jax.named_scope("experts"):
+        h = _act(jnp.einsum("gecd,edf->gecf", expert_in, params["w_gate"]),
+                 cfg.act)
+        h = h * jnp.einsum("gecd,edf->gecf", expert_in, params["w_up"])
+        expert_out = jnp.einsum("gecf,efd->gecd", h, params["w_down"])
+    with jax.named_scope("router"):
+        expert_out = jnp.pad(expert_out,
+                             ((0, 0), (0, 0), (0, 1), (0, 0)))  # sentinel -> 0
+        flat_out = expert_out.reshape(g, e * (capacity + 1), d)
+        y = jnp.zeros((g, tg, d), tokens.dtype)
+        for slot in range(k):
+            idx = gate_idx[:, :, slot] * (capacity + 1) + safe_pos[:, :, slot]
+            picked = jnp.take_along_axis(flat_out, idx[..., None], axis=1)
+            y = y + picked * gate_vals[:, :, slot, None].astype(tokens.dtype)
+    return y, load
+
+
 def moe_apply(params: Dict, x: jax.Array, cfg: ArchConfig,
               capacity_factor: float = 1.25,
               no_drop: bool = False, groups: int = 1,
               group_sharding: Optional[Any] = None
-              ) -> Tuple[jax.Array, jax.Array]:
-    """Top-k capacity-based dispatch (einsum MoE) with group-limited routing.
+              ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """Routed experts plus the shared ones.  Two dispatches:
 
-    ``groups`` splits the b*s tokens into G independent routing groups, each
-    with capacity ``cf * (t/G) * k / e``.  This is a2a expert parallelism in
-    pjit form: the grouped buffers (G, e, cap_g, d) are token-group-sharded
-    before the expert matmul and expert-sharded inside it — the reshard XLA
-    inserts between the two IS the all-to-all.  Per-device dispatch memory
-    drops from O(e * cap * d) (global capacity, ~40 GB for deepseek-v2 at
-    524k tokens/client) to O(e * cap_g * d / TP) (~0.3 GB).
+    * grouped (where the layer holds a share of the routed experts, as one
+      chip of an expert-parallel deployment): the drop-free grouped-matmul
+      path of ``_moe_grouped``; the work follows the routed rows.
+    * capacity (a layer holding every expert, ``_moe_capacity``): with
+      ``no_drop`` one group of capacity t, exact (decode and serving);
+      else ``groups`` splits the b*s tokens into G independent token
+      groups, each with capacity ``cf * (t/G) * k / e``.  The sharded
+      plans of ``launch/specs.py`` run this path: the SPMD partitioner
+      cannot split the grouped-matmul kernel over their expert-sharded
+      tables.  This is a2a expert parallelism in pjit form: the grouped
+      buffers (G, e, cap_g, d) are token-group-sharded before the expert
+      matmul and expert-sharded inside it — the reshard XLA inserts
+      between the two IS the all-to-all.  Per-device dispatch memory drops from
+      O(e * cap * d) (global capacity, ~40 GB for deepseek-v2 at 524k
+      tokens/client) to O(e * cap_g * d / TP) (~0.3 GB).
 
-    Returns (output, aux_loss).  The load-balance aux loss stays *client
-    local* under DFL — routing statistics never leave the client (privacy
-    note in DESIGN.md §4).
+    The router, its balance loss, the dispatch and the combine run under
+    the ``router`` named scope, the expert matmuls under ``experts``.
+
+    Returns (output, aux_loss, load_max): ``load_max`` is the largest count
+    of token-slots routed to one held expert over their mean.  The
+    load-balance aux loss stays *client local* under DFL — routing
+    statistics never leave the client (privacy note in DESIGN.md §4).
     """
     moe = cfg.moe
     b, s, d = x.shape
     e, k = moe.num_experts, moe.top_k
     t = b * s
-    g = groups if (not no_drop and t % max(groups, 1) == 0) else 1
-    tg = t // g
-    tokens = x.reshape(g, tg, d)
+    grouped = moe.held != e
+    g = groups if not (grouped or no_drop) and t % max(groups, 1) == 0 else 1
+    tokens = x.reshape(g, t // g, d)
     if group_sharding is not None and g > 1:
         tokens = jax.lax.with_sharding_constraint(tokens, group_sharding)
-    logits = jnp.einsum("gtd,de->gte", tokens.astype(jnp.float32),
-                        params["router"].astype(jnp.float32))
-    probs = jax.nn.softmax(logits, axis=-1)
-    gate_vals, gate_idx = jax.lax.top_k(probs, k)               # (g, tg, k)
-    gate_vals = gate_vals / (gate_vals.sum(-1, keepdims=True) + 1e-9)
-
-    # load-balance aux loss (Switch-style): E * sum_e f_e * p_e
-    me = probs.mean((0, 1))
-    ce = jnp.zeros((e,), jnp.float32).at[gate_idx.reshape(-1)].add(1.0) / (t * k)
-    aux = e * jnp.sum(me * ce) * moe.router_aux_weight
-
-    # decode paths must be drop-free (capacity == tokens covers worst-case
-    # routing); training uses the usual 1.25x factor per group.
-    capacity = tg if no_drop else max(1, int(capacity_factor * tg * k / e))
-    # position of each (token, slot) within its expert's per-group buffer
-    onehot = jax.nn.one_hot(gate_idx, e, dtype=jnp.int32)       # (g, tg, k, e)
-    flat = onehot.reshape(g, tg * k, e)
-    pos_in_expert = (jnp.cumsum(flat, axis=1) - flat).reshape(g, tg, k, e)
-    pos = (pos_in_expert * onehot).sum(-1)                      # (g, tg, k)
-    keep = pos < capacity
-    gate_vals = gate_vals * keep
-
-    # Gather-based dispatch: scatter only TOKEN INDICES (tiny, s32) into the
-    # (g, e, cap) slot table, then build expert inputs with a batched
-    # gather.  Scatter-adding full token VECTORS into (g, e, cap, d) defeats
-    # the SPMD partitioner (it replicates the buffer across all groups —
-    # ~21 GB/device for deepseek-v2 at 32k prefill); the batched gather
-    # partitions cleanly along the group axis.  Slot `capacity` / token id
-    # `tg` are the drop sentinels.
-    safe_pos = jnp.where(keep, pos, capacity)                   # (g, tg, k)
-    grange = jnp.arange(g)[:, None]
-    token_ids = jnp.broadcast_to(jnp.arange(tg), (g, tg))
-    slot_token = jnp.full((g, e, capacity + 1), tg, jnp.int32)
-    for slot in range(k):                                       # k small/static
-        slot_token = slot_token.at[
-            grange, gate_idx[:, :, slot], safe_pos[:, :, slot]].set(token_ids)
-    slot_token = slot_token[:, :, :capacity]                    # (g, e, cap)
-    tokens_pad = jnp.pad(tokens, ((0, 0), (0, 1), (0, 0)))      # sentinel -> 0
-    expert_in = jnp.take_along_axis(
-        tokens_pad, slot_token.reshape(g, e * capacity)[..., None],
-        axis=1).reshape(g, e, capacity, d)
-    h = _act(jnp.einsum("gecd,edf->gecf", expert_in, params["w_gate"]),
-             cfg.act)
-    h = h * jnp.einsum("gecd,edf->gecf", expert_in, params["w_up"])
-    expert_out = jnp.einsum("gecf,efd->gecd", h, params["w_down"])
-    expert_out = jnp.pad(expert_out,
-                         ((0, 0), (0, 0), (0, 1), (0, 0)))     # sentinel -> 0
-    flat_out = expert_out.reshape(g, e * (capacity + 1), d)
-    y = jnp.zeros((g, tg, d), x.dtype)
-    for slot in range(k):
-        idx = gate_idx[:, :, slot] * (capacity + 1) + safe_pos[:, :, slot]
-        picked = jnp.take_along_axis(flat_out, idx[..., None], axis=1)
-        y = y + picked * gate_vals[:, :, slot, None].astype(x.dtype)
+    with jax.named_scope("router"):
+        probs, gate_vals, gate_idx = moe_route(params, tokens, cfg)
+        rows = b if moe.seq_aux else 1
+        aux = _balance_loss(probs.reshape(rows, -1, e),
+                            gate_idx.reshape(rows, -1, k), cfg)
+    if grouped:
+        y, counts = _moe_grouped(params, tokens[0], gate_vals[0],
+                                 gate_idx[0], cfg)
+        load = _load_max(counts)
+    else:
+        tg = t // g
+        capacity = tg if no_drop else max(1, int(capacity_factor * tg * k / e))
+        y, load = _moe_capacity(params, tokens, gate_vals, gate_idx, cfg,
+                                capacity)
     if "shared" in params:
         y = y + mlp_apply(params["shared"], tokens, cfg.act)
-    return y.reshape(b, s, d), aux
+    return y.reshape(b, s, d), aux, load

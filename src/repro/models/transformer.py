@@ -40,15 +40,17 @@ class ApplyOptions:
 
     attn_impl: str = "reference"     # reference | pallas
     remat: bool = True
-    moe_no_drop: bool = False        # exact (capacity=t) MoE — tests/serving
+    # exact (capacity=t) MoE — tests/serving; a layer holding a share of
+    # the experts is always drop-free (the grouped matmul)
+    moe_no_drop: bool = False
     capacity_factor: float = 1.25
     # Megatron-style sequence parallelism: a NamedSharding for the logical
     # residual stream (b, s, d), applied at every layer-stack boundary so
     # the activations saved by scan-backward are sharded (e.g. seq over the
     # "model" axis).  None = let the partitioner decide.
     act_sharding: Optional[Any] = None
-    # Group-limited MoE routing (expert parallelism): tokens are split into
-    # ``moe_groups`` groups, each routed with its own capacity; the
+    # Token groups of the capacity dispatch (expert parallelism): tokens
+    # are split into ``moe_groups`` groups, each with its own capacity; the
     # group->expert reshard is the all-to-all of a2a expert parallelism.
     # moe_group_sharding: NamedSharding for the grouped (G, t/G, d) tokens.
     moe_groups: int = 1
@@ -132,12 +134,17 @@ def block_init(key, cfg: ArchConfig, kind: str, is_moe: bool,
 def block_apply(params: Dict, x: jax.Array, cfg: ArchConfig, kind: str,
                 is_moe: bool, *, memory: Optional[jax.Array] = None,
                 opts: ApplyOptions = DEFAULT_OPTS,
-                causal: bool = True) -> Tuple[jax.Array, jax.Array]:
-    """Full-sequence block. Returns (x, aux_loss).  The sequence mixer
+                causal: bool = True
+                ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """Full-sequence block. Returns (x, aux_loss, load_max), the last the
+    MoE layer's routing counter (0 elsewhere).  The sequence mixer
     (attention, or a Mamba mixer) with its norm and residual runs under the
     ``attention`` named scope and the FFN sublayer under ``mlp``, so a
-    device trace of the compiled program attributes each op to one."""
+    device trace of the compiled program attributes each op to one; the
+    modules name their own parts inside (``latent_kv``, ``router``,
+    ``experts``)."""
     aux = jnp.zeros((), jnp.float32)
+    load = jnp.zeros((), jnp.float32)
     with jax.named_scope("attention"):
         h = nn.rmsnorm_apply(params["ln1"], x, cfg.norm_eps)
         if kind == "mamba":
@@ -167,17 +174,17 @@ def block_apply(params: Dict, x: jax.Array, cfg: ArchConfig, kind: str,
         with jax.named_scope("mlp"):
             h = nn.rmsnorm_apply(params["ln2"], x, cfg.norm_eps)
             if is_moe:
-                ff, aux = nn.moe_apply(params["ffn"], h, cfg,
-                                       capacity_factor=opts.capacity_factor,
-                                       no_drop=opts.moe_no_drop,
-                                       groups=opts.moe_groups,
-                                       group_sharding=opts.moe_group_sharding)
+                ff, aux, load = nn.moe_apply(
+                    params["ffn"], h, cfg,
+                    capacity_factor=opts.capacity_factor,
+                    no_drop=opts.moe_no_drop, groups=opts.moe_groups,
+                    group_sharding=opts.moe_group_sharding)
             else:
                 ff = nn.mlp_apply(params["ffn"], h, cfg.act)
             if "post_ln2" in params:
                 ff = nn.rmsnorm_apply(params["post_ln2"], ff, cfg.norm_eps)
             x = x + ff
-    return x, aux
+    return x, aux, load
 
 
 # ---------------------------------------------------------------------------
@@ -268,31 +275,33 @@ def _head(params, cfg: ArchConfig, x: jax.Array) -> jax.Array:
 
 
 def _run_stack(params, cfg: ArchConfig, x: jax.Array, *,
-               memory=None, causal=True,
-               opts: ApplyOptions = DEFAULT_OPTS) -> Tuple[jax.Array, jax.Array]:
+               memory=None, causal=True, opts: ApplyOptions = DEFAULT_OPTS
+               ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """Returns (x, summed aux loss, largest MoE load_max over layers)."""
     plan = stack_plan(cfg)
     aux = jnp.zeros((), jnp.float32)
+    load = jnp.zeros((), jnp.float32)
     for i, blk in enumerate(params.get("prefix", ())):
         kind, _ = _layer_flags(cfg, i)
-        x, a = block_apply(blk, x, cfg, kind, False, memory=memory,
-                           opts=opts, causal=causal)
+        x, a, _ = block_apply(blk, x, cfg, kind, False, memory=memory,
+                              opts=opts, causal=causal)
         aux = aux + a
 
     def period_body(carry, period_params):
-        x, aux = carry
+        x, aux, load = carry
         x = opts.constrain(x)        # shard the scan-carry residual stream
         for i in range(plan.period):
             kind, is_moe = _layer_flags(cfg, plan.num_prefix + i)
-            x, a = block_apply(period_params[i], x, cfg, kind, is_moe,
-                               memory=memory, opts=opts,
-                               causal=causal)
-            aux = aux + a
-        return (x, aux), None
+            x, a, l = block_apply(period_params[i], x, cfg, kind, is_moe,
+                                  memory=memory, opts=opts,
+                                  causal=causal)
+            aux, load = aux + a, jnp.maximum(load, l)
+        return (x, aux, load), None
 
     body = jax.checkpoint(period_body) if opts.remat else period_body
-    (x, aux), _ = jax.lax.scan(body, (opts.constrain(x), aux),
-                               params["stack"])
-    return x, aux
+    (x, aux, load), _ = jax.lax.scan(body, (opts.constrain(x), aux, load),
+                                     params["stack"])
+    return x, aux, load
 
 
 def encode(params, cfg: ArchConfig, frames: jax.Array,
@@ -305,8 +314,8 @@ def encode(params, cfg: ArchConfig, frames: jax.Array,
     def body(carry, period_params):
         x, = carry
         x = opts.constrain(x)
-        x, _ = block_apply(period_params[0], x, cfg, "global", False,
-                           causal=False, opts=opts)
+        x, _, _ = block_apply(period_params[0], x, cfg, "global", False,
+                              causal=False, opts=opts)
         return (x,), None
 
     (x,), _ = jax.lax.scan(jax.checkpoint(body), (opts.constrain(frames),),
@@ -317,7 +326,15 @@ def encode(params, cfg: ArchConfig, frames: jax.Array,
 def forward_hidden(params, cfg: ArchConfig, batch: Dict[str, jax.Array], *,
                    opts: ApplyOptions = DEFAULT_OPTS
                    ) -> Tuple[jax.Array, jax.Array]:
-    """Trunk only: final hidden states over the token positions (pre-head).
+    """Trunk only: final hidden states over the token positions (pre-head)
+    and the aux loss."""
+    x, aux, _ = _trunk(params, cfg, batch, opts)
+    return x, aux
+
+
+def _trunk(params, cfg: ArchConfig, batch: Dict[str, jax.Array],
+           opts: ApplyOptions) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """``forward_hidden`` with the MoE layers' largest ``load_max``.
 
     ``batch`` keys by family:
        text:  tokens (b, s)
@@ -332,8 +349,8 @@ def forward_hidden(params, cfg: ArchConfig, batch: Dict[str, jax.Array], *,
     n_text = x.shape[1]
     if cfg.frontend is not None and cfg.frontend.kind == "vision_patches":
         x = jnp.concatenate([batch["patch_embeds"].astype(x.dtype), x], axis=1)
-    x, aux = _run_stack(params, cfg, x, memory=memory, opts=opts)
-    return x[:, -n_text:], aux
+    x, aux, load = _run_stack(params, cfg, x, memory=memory, opts=opts)
+    return x[:, -n_text:], aux, load
 
 
 def forward(params, cfg: ArchConfig, batch: Dict[str, jax.Array], *,
@@ -364,15 +381,22 @@ def make_loss_fn(cfg: ArchConfig, opts: ApplyOptions = DEFAULT_OPTS,
       (whose gather would force a full-vocab all-gather).
 
     The head and the cross-entropy run under the ``lm_head`` named scope.
+    The aux dict holds the cross-entropy (``nll``), the balance loss
+    (``aux``) and, for an MoE model, ``moe_load_max``: over the held
+    experts of every MoE layer, the largest count of routed token-slots
+    over their mean.
     """
 
     def loss_fn(params, batch, rng):
         del rng
-        x, aux = forward_hidden(params, cfg, batch, opts=opts)
+        x, aux, load = _trunk(params, cfg, batch, opts)
         with jax.named_scope("lm_head"):
             nll_mean = chunked_nll(params, batch, x)
         loss = nll_mean + aux
-        return loss, {"nll": nll_mean, "aux": aux}
+        out = {"nll": nll_mean, "aux": aux}
+        if cfg.moe is not None:
+            out["moe_load_max"] = load
+        return loss, out
 
     def chunked_nll(params, batch, x):
         xs = x[:, :-1]                                       # predict t+1
@@ -479,7 +503,7 @@ def _block_decode(params, cache, x, cfg: ArchConfig, kind: str, is_moe: bool,
     if "ffn" in params:
         h = nn.rmsnorm_apply(params["ln2"], x, cfg.norm_eps)
         if is_moe:
-            ff, _ = nn.moe_apply(params["ffn"], h, cfg, no_drop=True)
+            ff, _, _ = nn.moe_apply(params["ffn"], h, cfg, no_drop=True)
         else:
             ff = nn.mlp_apply(params["ffn"], h, cfg.act)
         if "post_ln2" in params:
@@ -604,7 +628,7 @@ def prefill(params, cfg: ArchConfig, batch: Dict[str, jax.Array], *,
         if "ffn" in blk_params:
             h = nn.rmsnorm_apply(blk_params["ln2"], x, cfg.norm_eps)
             if is_moe:
-                ff, _ = nn.moe_apply(blk_params["ffn"], h, cfg,
+                ff, _, _ = nn.moe_apply(blk_params["ffn"], h, cfg,
                                      capacity_factor=opts.capacity_factor,
                                      no_drop=opts.moe_no_drop,
                                      groups=opts.moe_groups,

@@ -41,7 +41,7 @@ def test_plans_param_budget():
 
 def test_supported_pairs_count():
     pairs = supported_pairs()
-    assert len(pairs) == 34          # 10 x 3 + 4 long-context archs
+    assert len(pairs) == 37          # 11 x 3 + 4 long-context archs
     longs = [a for a, s in pairs if s == "long_500k"]
     assert sorted(longs) == sorted([
         "mixtral_8x22b", "gemma2_27b", "jamba_1_5_large_398b",

@@ -248,3 +248,78 @@ def test_bucketed_gossip_round_compiles_at_model_flat_size(one_chip):
             s((m, m), jnp.float32), s((m, d), jnp.int8),
             s((m, d // chunk), jnp.float32), s((m, d), jnp.float32),
             s((m, d), jnp.float32), s((m, d), jnp.float32)).compile())
+
+
+DEEPSEEK_CELL = "deepseek-v2-lite-5l-8e.m2-mean.local10"
+
+
+def test_deepseek_cell_epoch_step_fits_through_the_grouped_matmul(one_chip):
+    """The benchmark cell ``deepseek-v2-lite-5l-8e.m2-mean.local10``'s
+    epoch step, built as its run builds it (M=2 servers of one client, B=1,
+    S=256, T_C=10; 5 layers, 8 of 64 experts held): it fits one chip, runs
+    the held experts through the grouped-matmul kernel, and holds no
+    (held, tokens, hidden) capacity buffer."""
+    import sys
+
+    root = pathlib.Path(__file__).resolve().parents[1]
+    if str(root) not in sys.path:
+        sys.path.insert(0, str(root))
+    from benchmarks.chip import harness
+    from repro.core.schedule import EpochSchedule as Sched
+
+    cell = harness.load_cell(DEEPSEEK_CELL)
+    c = cell.config
+    fed = harness.Federation(c, cell.traffic)
+    params = jax.eval_shape(fed._weights, jax.random.key(0))
+    state = jax.eval_shape(lambda p: init_dfl_state(
+        fed.engine.cfg, p, fed.optimizer, jax.random.key(1)), params)
+    m, n = c["servers"], c["clients_per_server"]
+    batch = {"tokens": jax.ShapeDtypeStruct(
+        (cell.traffic["t_client"], m, n, c["per_client_batch"],
+         c["seq_len"]), jnp.int32)}
+    sched = Sched(jax.ShapeDtypeStruct((m, n), jnp.float32),
+                  jax.ShapeDtypeStruct((m, m), jnp.float32))
+    compiled = fed.engine._step().lower(
+        *(_shaped(t, jax.tree.map(lambda _: one_chip, t))
+          for t in (state, batch, sched))).compile()
+    peak = compiled.memory_analysis().peak_memory_in_bytes
+    assert 0 < peak < HBM_BYTES, peak
+    hlo = compiled.as_text()
+    assert 'custom_call_target="tpu_custom_call"' in hlo
+    held = fed.arch.moe.held
+    tokens = c["per_client_batch"] * c["seq_len"]
+    capacity = rf"\[(?:\d+,)*{held},{tokens},{c['hidden_size']}\]"
+    assert not re.search(capacity, hlo), re.search(capacity, hlo).group()
+
+
+@pytest.fixture(scope="module")
+def pod_topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:16x16")
+    except Exception as e:  # noqa: BLE001 - any failure means "cannot"
+        pytest.skip(f"no v5e:16x16 topology can be described here: {e}")
+
+
+def test_moe_decode_32k_partitions_over_the_serving_mesh(pod_topo,
+                                                         monkeypatch):
+    """DeepSeek-V2-Lite's ``decode_32k`` serve step, built as
+    ``launch/specs.py`` builds it, over a described 16x16 v5e serving mesh
+    with the expert tables sharded over "model".  Its layers hold every
+    expert, so they keep the capacity dispatch: the SPMD partitioner
+    refuses to split a Mosaic kernel, and the grouped matmul must not
+    appear.  Each chip's share fits its HBM."""
+    from repro.configs import INPUT_SHAPES
+    from repro.launch import specs
+
+    mesh = Mesh(np.array(pod_topo.devices).reshape(16, 16),
+                ("data", "model"))
+    monkeypatch.setattr(specs, "make_serve_mesh",
+                        lambda multi_pod=False: mesh)
+    bundle = specs.build_decode_lowering("deepseek_v2_lite",
+                                         INPUT_SHAPES["decode_32k"])
+    compiled = bundle.jitted.lower(*bundle.args).compile()
+    assert "tpu_custom_call" not in compiled.as_text()
+    peak = compiled.memory_analysis().peak_memory_in_bytes
+    assert 0 < peak < HBM_BYTES, peak
